@@ -1,13 +1,6 @@
 """Per-kernel microbenchmarks: Pallas (interpret on CPU / compiled on
-TPU) vs the jnp reference path, across the engine's working sizes.
-On CPU the relative numbers reflect interpret-mode overhead — the
-correctness contract is what CI checks; on TPU this bench reports the
-fusion win.
-
-Also sweeps the autotuner's block-shape candidates per capacity rung
-(``kernels.autotune``) and emits one row per (kernel, rung, block), so
-the block-shape landscape lands in the ``BENCH_*.json`` trajectory like
-every other bench (``--json``, or via ``benchmarks.run``)."""
+TPU) vs the jnp reference path.  On CPU the relative numbers reflect
+interpret-mode overhead — the correctness contract is what CI checks."""
 
 from __future__ import annotations
 
@@ -18,43 +11,16 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops, ref
-from repro.kernels.autotune import autotune
 
 from .common import emit, timeit, write_json
 
 
-def block_sweep_section(rungs, repeats: int) -> None:
-    """One row per candidate block shape at each capacity rung — the
-    same sweep the calibration artifact caches winners from."""
-    block_q, block_t, raw = autotune(rungs, repeats=repeats)
-    for (kind, rung, blk), ns in sorted(raw.items()):
-        win = (block_q if kind == "block_q" else block_t)[rung]
-        emit(f"kernels/sweep/{kind}/r{rung}/b{blk}", ns / 1e3,
-             f"winner={win};chosen={blk == win}")
-
-
 def main() -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--smoke", action="store_true",
-                    help="CI mode: smaller block-sweep rungs")
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also write the emitted rows as JSON")
     args, _ = ap.parse_known_args()
     rng = np.random.default_rng(0)
-
-    # sorted_intersect: class-id membership at paper-ish sizes
-    for n_hay, n_q in [(1 << 10, 1 << 12), (1 << 14, 1 << 14)]:
-        hay = np.sort(rng.choice(n_hay * 8, n_hay, replace=False)).astype(np.int32)
-        q = rng.integers(0, n_hay * 8, n_q).astype(np.int32)
-        hj, qj = jnp.asarray(hay), jnp.asarray(q)
-        f_k = jax.jit(lambda h, q: ops.sorted_member_mask(h, n_hay, q))
-        f_r = jax.jit(lambda h, q: ref.sorted_member_mask(h, n_hay, q))
-        f_k(hj, qj).block_until_ready()
-        f_r(hj, qj).block_until_ready()
-        emit(f"kernels/sorted_intersect/{n_hay}x{n_q}/pallas",
-             timeit(lambda: f_k(hj, qj).block_until_ready()), "")
-        emit(f"kernels/sorted_intersect/{n_hay}x{n_q}/jnp_ref",
-             timeit(lambda: f_r(hj, qj).block_until_ready()), "")
 
     # fingerprint: 2-column mix at build sizes
     n = 1 << 15
@@ -82,13 +48,10 @@ def main() -> None:
     emit(f"kernels/segment_softmax/{e}x{d}/jnp_ref",
          timeit(lambda: f_r(scores, seg).block_until_ready()), "")
 
-    block_sweep_section(rungs=(1 << 10,) if args.smoke
-                        else (1 << 10, 1 << 12, 1 << 14),
-                        repeats=2 if args.smoke else 3)
     jax.clear_caches()
 
     if args.json:
-        write_json(args.json, bench="bench_kernels", smoke=args.smoke)
+        write_json(args.json, bench="bench_kernels")
 
 
 if __name__ == "__main__":

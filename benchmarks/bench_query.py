@@ -213,7 +213,7 @@ def calibrated_section(table_path: str, iters: int) -> bool:
     from benchmarks.common import DATASETS, emit, timeit
 
     table = costmodel.DeviceCostTable.load(table_path)
-    costmodel.activate(table)  # tuned blocks + VMEM ceiling for kernels
+    costmodel.activate(table)  # the VMEM ceiling for kernels
     g = DATASETS["skewed-hub"]()
     idx = cindex.build(g, 2)
     probes = [(name, instantiate_template(name, labels))

@@ -1,7 +1,6 @@
-"""Build the device cost table: micro-calibration + block autotuning +
-online refinement, written as the versioned JSON artifact every other
-consumer reads (optimizer, ``estimate_caps``, the kernel wrappers, the
-lifecycle checkpoint codec, CI).
+"""Build the device cost table: micro-calibration + online refinement,
+written as the versioned JSON artifact every other consumer reads
+(optimizer, ``estimate_caps``, the lifecycle checkpoint codec, CI).
 
     PYTHONPATH=src python -m benchmarks.calibrate \
         [--smoke] [--out BENCH_costtable.json] [--json rows.json] \
@@ -14,9 +13,7 @@ Stages (each emits bench rows, so the calibration itself lands in the
    starts the gated probe templates at (``costmodel.ladder_rungs``);
 2. **calibrate** — per-operator affine stage constants fitted from the
    synthetic micro-benchmarks at those rungs;
-3. **autotune** — Pallas ``block_q``/``block_t`` sweeps per rung
-   (``kernels.autotune``), winners cached in the table;
-4. **refine** — end-to-end probe queries on a real engine correct the
+3. **refine** — end-to-end probe queries on a real engine correct the
    synthetic scale (``costmodel.refine_with_engine``), and any
    ``--refine-from`` bench JSONs from previous runs feed
    ``refine_from_trajectory`` — the loop that makes every CI run
@@ -51,7 +48,6 @@ def main() -> None:
     from repro.core import index as cindex
     from repro.core.engine import Engine
     from repro.core.query import instantiate_template
-    from repro.kernels.autotune import autotune
 
     from benchmarks.bench_query import OPT_EXTRA, OPT_GATED, OPT_RUNG_GATED
     from benchmarks.common import DATASETS, emit, write_json
@@ -79,14 +75,6 @@ def main() -> None:
              f"fixed_ns={c.fixed_ns:.0f};per_row_ns={c.per_row_ns:.3f};"
              f"n_samples={len(table.samples.get(op, []))}")
 
-    block_q, block_t, raw = autotune(rungs, repeats=repeats)
-    table.block_q.update(block_q)
-    table.block_t.update(block_t)
-    for (kind, rung, blk), ns in sorted(raw.items()):
-        win = (block_q if kind == "block_q" else block_t)[rung]
-        emit(f"calibrate/{kind}/r{rung}/b{blk}", ns / 1e3,
-             f"winner={win};chosen={blk == win}")
-
     scale = costmodel.refine_with_engine(table, engine, probes,
                                          repeats=repeats)
     emit("calibrate/refine/engine", 0.0,
@@ -112,8 +100,7 @@ def main() -> None:
     table.save(args.out)
     emit("calibrate/artifact", 0.0,
          f"out={args.out};device={table.device_kind};"
-         f"vmem_words={table.vmem_words};"
-         f"rungs_tuned={len(table.block_q)}")
+         f"vmem_words={table.vmem_words}")
 
     if args.json:
         write_json(args.json, bench="calibrate", smoke=args.smoke,

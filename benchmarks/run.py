@@ -24,7 +24,7 @@ BENCHES = {
     "table5": "benchmarks.bench_update",  # maintenance (+ tables 6/7)
     "fig14": "benchmarks.bench_k",  # behavior in k (+ fig 15)
     "fig11": "benchmarks.bench_scalability",  # graph-size scaling
-    "kernels": "benchmarks.bench_kernels",  # Pallas vs jnp ref + block sweeps
+    "kernels": "benchmarks.bench_kernels",  # Pallas vs jnp ref
     "calibrate": "benchmarks.calibrate",  # device cost table artifact (PR 8)
     "throughput": "benchmarks.bench_throughput",  # serving qps (PR 1)
     "adaptive": "benchmarks.bench_adaptive",  # drifting-workload mining (PR 5)
@@ -42,6 +42,9 @@ def main() -> None:
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="serialize all emitted rows to PATH")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     todo = args.only or list(BENCHES)
     print("name,us_per_call,derived")
     t0 = time.time()

@@ -1,56 +1,27 @@
-"""Version-compatibility shims for the jax mesh / shard_map API.
+"""The mesh / shard_map calls of the sharded engine, in one place.
 
-The sharded engine targets the modern API (``jax.shard_map`` with
-``check_vma``, ``jax.sharding.AxisType``, ``jax.sharding.set_mesh``);
-older installs (<= 0.4.x) spell these ``jax.experimental.shard_map``
-with ``check_rep``, no axis types, and the Mesh context manager.  All
-mesh-touching code goes through this module so both generations work.
+All mesh-touching code goes through this module, so the choices made
+here (Auto axis types, replication checking off) hold everywhere.
 """
 
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5: explicit axis types on mesh construction
-    from jax.sharding import AxisType  # noqa: F401
-
-    _HAS_AXIS_TYPES = True
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
-    _HAS_AXIS_TYPES = False
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis types where supported; on jax
-    builds predating ``jax.make_mesh`` (< 0.4.35), assemble the Mesh
-    from ``mesh_utils`` directly."""
-    if _HAS_AXIS_TYPES:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(shape, axes)
-    from jax.experimental import mesh_utils
-
-    return jax.sharding.Mesh(mesh_utils.create_device_mesh(shape), axes)
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def set_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    New jax has ``jax.sharding.set_mesh``; on older versions the Mesh
-    object itself is the context manager."""
-    if hasattr(jax.sharding, "set_mesh"):
-        return jax.sharding.set_mesh(mesh)
-    return mesh
+    """Context manager installing ``mesh`` as the ambient mesh."""
+    return jax.sharding.set_mesh(mesh)
 
 
 def shard_map(f, mesh, in_specs, out_specs):
-    """``shard_map`` with replication checking off (our bodies mix
+    """``jax.shard_map`` with replication checking off (our bodies mix
     replicated and sharded outputs, which the checker rejects)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental import shard_map as _sm
-
-    return _sm.shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -33,16 +33,19 @@ The overflow-ladder contract (canonical statement — ``core.engine``,
 ``core.distributed`` and the capacity estimators all defer here):
 every relation is capacity-padded, and any operator that would drop
 rows sets a *sticky* overflow flag that propagates to the plan's final
-result instead of raising.  The host driver is the only party that
-reacts: it re-runs the whole plan with every capacity doubled, and
-after three doublings from a (possibly far-too-tight) estimate it
-jumps to at least the worst-case ``default_caps`` so the ladder cannot
-exhaust below where a stats-free engine would have started.  All
+result instead of raising.  The flag is a word of bits, one per
+capacity kind (class, pair or join: the ``OVF_*`` bits): the operator
+that drops rows sets its capacity's bit, and ORing inputs carries the
+bits to the plan's result.  The host is the only party that reacts: it
+re-runs the whole plan with those capacities doubled, and after three
+doublings from a (possibly far-too-tight) estimate it jumps them to at
+least the worst-case ``default_caps`` so the ladder cannot exhaust
+below where a stats-free engine would have started.  All
 capacities live on the power-of-two ladder, so retried plans land on
 already-compiled executables.  Variations are mechanical, not
 semantic: the batched path keeps one sticky flag per lane and retries
-only the lanes that tripped; the sharded backend psum-reduces per-shard
-flags so every shard and the host agree on the same retry decision.
+only the lanes that tripped; the sharded backend ORs the per-shard
+words so every shard and the host agree on the same retry decision.
 This is the honest dynamic->static bridge — estimates and optimizer
 cost models can be arbitrarily wrong about *sizes* without ever being
 wrong about *answers*.
@@ -73,6 +76,7 @@ from __future__ import annotations
 import abc
 import dataclasses
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -81,7 +85,6 @@ import numpy as np
 from . import relational as R
 from .index import DeviceIndexArrays
 from .paths import _recap
-from repro.kernels import ops as kops
 
 
 @dataclasses.dataclass(frozen=True)
@@ -94,6 +97,11 @@ class QueryCaps:
 
     def doubled(self) -> "QueryCaps":
         return QueryCaps(self.class_cap * 2, self.pair_cap * 2, self.join_cap * 2)
+
+
+#: Capacity kinds an overflow is attributed to: the bits of the
+#: relations' overflow word on the query path (0 = no overflow).
+OVF_CLASS, OVF_PAIR, OVF_JOIN = 1, 2, 4
 
 
 def default_caps(index) -> QueryCaps:
@@ -110,11 +118,12 @@ def default_caps(index) -> QueryCaps:
 
 
 def _join_pairs(a: R.Relation, b: R.Relation, join_cap: int, pair_cap: int) -> R.Relation:
-    """(v,u) ⋈ (x,y) on u == x -> distinct (v, y).  b sorted by (x, y)."""
+    """(v,u) ⋈ (x,y) on u == x -> distinct (v, y).  b sorted by (x, y).
+    The expansion overflows ``join_cap``, the deduped result ``pair_cap``."""
     out = R.expansion_join(a, b, a_on=[1], out_cols=[("a", 0), ("b", 1)],
-                           out_capacity=join_cap)
+                           out_capacity=join_cap, bit=OVF_JOIN)
     out = R.rel_unique(R.rel_sort(out, num_keys=2), 2)
-    return _recap(out, pair_cap)
+    return _recap(out, pair_cap, OVF_PAIR)
 
 
 # ---------------------------------------------------------------------- #
@@ -155,15 +164,15 @@ class PlanOps:
         valid = idx < length
         src = jnp.clip(start + idx, 0, self.l2c_cls.shape[0] - 1)
         ids = jnp.where(valid, self.l2c_cls[src], R.SENTINEL)
-        ovf = length > cap
-        return R.Relation((ids,), jnp.minimum(length, cap).astype(R.I32), ovf)
+        return R.Relation((ids,), jnp.minimum(length, cap).astype(R.I32),
+                          R.overflow_bit(length > cap, OVF_CLASS))
 
     def conj_classes(self, a: R.Relation, b: R.Relation) -> R.Relation:
-        """Prop. 4.1 on device: sorted-intersect Pallas kernel."""
-        mask = kops.sorted_member_mask(b.cols[0], b.count, a.cols[0])
-        out = R.rel_compact(a, mask > 0)
-        # an undersized RIGHT list means missing matches: sticky
-        return R.Relation(out.cols, out.count, out.overflow | b.overflow)
+        """Prop. 4.1 on device: ``a``'s class ids found in the sorted list
+        ``b`` (binary search), compacted.  An undersized ``b`` means
+        missing matches, so its overflow is sticky on the result."""
+        with jax.named_scope("conj_classes"):
+            return R.rel_intersect(a, b, 1)
 
     def conj_id_classes(self, classes: R.Relation) -> R.Relation:
         cyc = self.class_cyclic[
@@ -175,21 +184,21 @@ class PlanOps:
 
     def materialize(self, classes: R.Relation, pair_cap: int) -> R.Relation:
         """classes -> sorted distinct (v, u).  Classes are disjoint, so the
-        expansion introduces no duplicate pairs.  The gather pass is the
-        ``expand_join`` Pallas kernel (fused binary search + payload
-        gather)."""
-        cid = jnp.clip(classes.cols[0], 0, self.class_starts.shape[0] - 2)
-        lo = self.class_starts[cid]
-        cnt = self.class_starts[cid + 1] - lo
-        cnt = jnp.where(R.valid_mask(classes), cnt, 0).astype(R.I32)
-        ends = jnp.cumsum(cnt, dtype=R.I32)
-        total = ends[-1]
-        v, u, _ = kops.expand_join_gather(
-            ends, lo, classes.cols[0], self.c2p_v, self.c2p_u, total, pair_cap
-        )
-        rel = R.Relation((v, u), jnp.minimum(total, pair_cap).astype(R.I32),
-                         classes.overflow | (total > pair_cap))
-        return R.rel_sort(rel, num_keys=2)
+        expansion introduces no duplicate pairs: each class id expands to
+        its CSR range of the I_c2p columns, then one sort by (v, u)."""
+        with jax.named_scope("materialize"):
+            cid = jnp.clip(classes.cols[0], 0, self.class_starts.shape[0] - 2)
+            lo = self.class_starts[cid]
+            cnt = self.class_starts[cid + 1] - lo
+            cnt = jnp.where(R.valid_mask(classes), cnt, 0).astype(R.I32)
+            _, bj, valid, total = R.expand_ranges(lo, cnt, pair_cap)
+            bj = jnp.clip(bj, 0, self.c2p_v.shape[0] - 1)
+            v = jnp.where(valid, self.c2p_v[bj], R.SENTINEL)
+            u = jnp.where(valid, self.c2p_u[bj], R.SENTINEL)
+            rel = R.Relation(
+                (v, u), jnp.minimum(total, pair_cap).astype(R.I32),
+                classes.overflow | R.overflow_bit(total > pair_cap, OVF_PAIR))
+            return R.rel_sort(rel, num_keys=2)
 
     def join_pairs(self, a: R.Relation, b: R.Relation, join_cap: int,
                    pair_cap: int) -> R.Relation:
@@ -208,13 +217,13 @@ class PlanOps:
         return R.Relation(
             (col, col),
             jnp.asarray(min(self.n_vertices, pair_cap), R.I32),
-            jnp.asarray(self.n_vertices > pair_cap))
+            R.overflow_bit(self.n_vertices > pair_cap, OVF_PAIR))
 
     # ---- epilogue ---- #
 
     def finish(self, pairs: R.Relation):
-        """Final (relation, overflow) of a plan — a distributed backend
-        reduces the per-shard sticky flags here."""
+        """Final (relation, overflow word) of a plan — a distributed
+        backend reduces the per-shard words here."""
         return pairs, pairs.overflow
 
 
@@ -230,6 +239,28 @@ class LocalOps(PlanOps):
         self.n_vertices = n_vertices
 
 
+#: Operators whose compiled form :func:`op_implementations` reports.
+SCOPED_OPS = ("conj_classes", "materialize")
+
+
+def op_implementations(hlo_text: str) -> dict:
+    """Which implementation each of :data:`SCOPED_OPS` compiled to, read
+    from a compiled program's text (``compiled.as_text()``): "pallas"
+    where an instruction under the operator's ``named_scope`` is a
+    ``tpu_custom_call``, "xla" where it has only XLA instructions, and
+    "absent" where the program holds no instruction of it."""
+    out = {}
+    for op in SCOPED_OPS:
+        # the scope shows as ".../op/..." or, under vmap, ".../vmap(op)/..."
+        scope = re.compile(rf"[/(]{op}[)/]")
+        lines = [ln for ln in hlo_text.splitlines() if scope.search(ln)]
+        if any("tpu_custom_call" in ln for ln in lines):
+            out[op] = "pallas"
+        else:
+            out[op] = "xla" if lines else "absent"
+    return out
+
+
 # ---------------------------------------------------------------------- #
 # plan walker — written once against the protocol
 # ---------------------------------------------------------------------- #
@@ -239,9 +270,9 @@ def run_plan_ops(ops: PlanOps, plan, caps: QueryCaps, lookup_ranges: jax.Array):
     """Execute a physical plan against a :class:`PlanOps` operator set.
 
     ``lookup_ranges``: (n_lookups, 2) int32 of (start, len) per LOOKUP
-    segment, in plan order.  Returns whatever ``ops.finish`` yields — for
-    every shipped backend a pair Relation (sorted distinct (v, u)) and
-    the sticky overflow flag.
+    segment, in plan order.  Returns what ``ops.finish`` yields: the pair
+    Relation (sorted distinct (v, u)) and its overflow word — 0, or the
+    ``OVF_*`` bits of the capacities that overflowed.
 
     ``plan`` may be a frozen plan or its :func:`repro.core.query.plan_shape`
     — the device computation only depends on the shape (LOOKUP nodes carry
@@ -413,7 +444,7 @@ def _run_program_lane(ops: PlanOps, caps: QueryCaps, stack_size: int,
 
         def slot(i):
             i = jnp.clip(i, 0, stack_size - 1)
-            return R.Relation((v[i], u[i]), cnt[i], jnp.asarray(False))
+            return R.Relation((v[i], u[i]), cnt[i], jnp.int32(0))
 
         top = slot(sp - 1)
         sec = slot(sp - 2)
@@ -421,7 +452,7 @@ def _run_program_lane(ops: PlanOps, caps: QueryCaps, stack_size: int,
             ops.lookup_classes(rng[0], rng[1], caps.class_cap), cap)
         cands = [
             R.Relation((sentinel_col, sentinel_col), jnp.asarray(0, R.I32),
-                       jnp.asarray(False)),  # NOP
+                       jnp.int32(0)),  # NOP
             lk,  # LOOKUP
             ops.join_pairs(sec, top, caps.join_cap, cap),  # JOIN
             ops.conj_pairs(sec, top),  # CONJ
@@ -431,20 +462,21 @@ def _run_program_lane(ops: PlanOps, caps: QueryCaps, stack_size: int,
         sel_v = jnp.stack([r.cols[0] for r in cands])[op]
         sel_u = jnp.stack([r.cols[1] for r in cands])[op]
         sel_c = jnp.stack([jnp.asarray(r.count, R.I32) for r in cands])[op]
-        sel_o = jnp.stack([jnp.asarray(r.overflow) for r in cands])[op]
+        sel_o = jnp.stack([jnp.asarray(r.overflow, R.I32)
+                           for r in cands])[op]
         widx = jnp.where(op == OP_NOP, -1,
                          sp + jnp.asarray(_OP_WRITE, R.I32)[op])
         mask = jnp.arange(stack_size, dtype=R.I32) == widx
         v = jnp.where(mask[:, None], sel_v[None, :], v)
         u = jnp.where(mask[:, None], sel_u[None, :], u)
         cnt = jnp.where(mask, sel_c, cnt)
-        ovf = ovf | (sel_o & (op != OP_NOP))
+        ovf = ovf | jnp.where(op != OP_NOP, sel_o, 0)
         sp = sp + jnp.asarray(_OP_DELTA, R.I32)[op]
         return (v, u, cnt, sp, ovf), None
 
     zeros = jnp.full((stack_size, cap), R.SENTINEL, R.I32)
     carry = (zeros, zeros, jnp.zeros((stack_size,), R.I32),
-             jnp.asarray(0, R.I32), jnp.asarray(False))
+             jnp.asarray(0, R.I32), jnp.int32(0))
     (v, u, cnt, _, ovf), _ = jax.lax.scan(step, carry, (opcodes, step_ranges))
     return ops.finish(R.Relation((v[0], u[0]), cnt[0], ovf))
 
@@ -489,18 +521,29 @@ class ExecutionBackend(abc.ABC):
     def run(self, shape, caps: QueryCaps, ranges: np.ndarray):
         """One query.  ``ranges`` (n_lookups, 2) -> (rows | None, overflow):
         sorted distinct (n, 2) int32 s-t pairs, or None when the sticky
-        overflow flag tripped (the caller retries with doubled caps)."""
+        overflow tripped (the caller retries with larger caps).
+        ``overflow`` is the int overflow word: 0 when clear, else the
+        ``OVF_*`` bits of the capacities to grow."""
 
     @abc.abstractmethod
     def run_batch(self, shape, caps: QueryCaps, ranges: np.ndarray):
         """Batch of same-shape queries.  ``ranges`` (batch, n_lookups, 2)
-        -> (list of rows-or-None per lane, (batch,) bool overflow)."""
+        -> (list of rows-or-None per lane, (batch,) int overflow words,
+        as :meth:`run`)."""
 
     def run_union_batch(self, opcodes: np.ndarray, caps: QueryCaps,
                         stack_size: int, step_ranges: np.ndarray):
         """Mixed-shape batch via the union executable.  ``opcodes``
         (batch, T), ``step_ranges`` (batch, T, 2); same result contract
         as :meth:`run_batch`.  Optional — guarded by ``supports_union``."""
+        raise NotImplementedError
+
+    def compile_batch(self, shape, caps: QueryCaps, ranges: np.ndarray):
+        """Compile, without running, the executable :meth:`run_batch`
+        dispatches for these arguments, and return it (a
+        ``jax.stages.Compiled``).  It lands in the same compile cache the
+        dispatch reads, so compiling ahead of traffic — several at once
+        in threads — takes compile time off the first requests."""
         raise NotImplementedError
 
     # -- async dispatch (pipelined drain) -- #
@@ -537,12 +580,16 @@ class LocalBackend(ExecutionBackend):
     def run(self, shape, caps: QueryCaps, ranges: np.ndarray):
         pairs, overflow = run_plan(self.arrays, shape, caps, self.n_vertices,
                                    jnp.asarray(ranges))
-        if bool(overflow):
-            return None, True
-        return R.to_numpy(pairs), False
+        if int(overflow):
+            return None, int(overflow)
+        return R.to_numpy(pairs), 0
 
     def run_batch(self, shape, caps: QueryCaps, ranges: np.ndarray):
         return self.harvest_batch(self.run_batch_async(shape, caps, ranges))
+
+    def compile_batch(self, shape, caps: QueryCaps, ranges: np.ndarray):
+        return run_plan_batch.lower(self.arrays, shape, caps, self.n_vertices,
+                                    jnp.asarray(ranges)).compile()
 
     def run_union_batch(self, opcodes: np.ndarray, caps: QueryCaps,
                         stack_size: int, step_ranges: np.ndarray):
@@ -570,7 +617,7 @@ class LocalBackend(ExecutionBackend):
         _, rel, overflow = handle
         overflow = np.asarray(overflow)
         results: list = [None] * overflow.shape[0]
-        ok = np.nonzero(~overflow)[0]
+        ok = np.nonzero(overflow == 0)[0]
         if ok.size:
             for lane, rows in zip(ok, R.batch_to_numpy(rel, lanes=ok)):
                 results[lane] = rows

@@ -127,7 +127,7 @@ def _lookup_pairs(a: PathArrays, start, length, cap: int) -> R.Relation:
     u = jnp.where(valid, a.l2p_u[src], R.SENTINEL)
     # rows within a seq block are sorted by (v, u) and distinct
     return R.Relation((v, u), jnp.minimum(length, cap).astype(R.I32),
-                      length > cap)
+                      R.overflow_bit(length > cap))
 
 
 @functools.partial(jax.jit, static_argnames=("plan", "caps", "n_vertices"))
@@ -157,7 +157,7 @@ def run_plan_path(a: PathArrays, plan, caps: QueryCaps, n_vertices: int,
             col = jnp.where(m, v, R.SENTINEL)
             return R.Relation((col, col),
                               jnp.asarray(min(n_vertices, caps.pair_cap), R.I32),
-                              jnp.asarray(n_vertices > caps.pair_cap))
+                              R.overflow_bit(n_vertices > caps.pair_cap))
         if kind == "conj_id":
             rel = ev(node[1])
             return R.rel_compact(rel, rel.cols[0] == rel.cols[1])

@@ -3,7 +3,7 @@ instruction stream.
 
 ``ShardedBackend`` (``core.distributed``) proved the distributed *math*:
 the one plan walker over hash-partitioned pair relations, exchanges on
-materialize/join, per-shard sticky overflow flags reduced so every party
+materialize/join, per-shard sticky overflow words reduced so every party
 agrees on retry.  But it lives in one process — ``shard_map`` over fake
 devices is a contract check, not scale-out.  This module ports exactly
 that math to a coordinator + N persistent **worker processes**:
@@ -206,7 +206,7 @@ def _k_embed(cols, count, overflow, unique: bool, out_cap: int):
     rel = R.rel_sort(R.Relation(cols, count, overflow))
     if unique:
         rel = R.rel_unique(rel)
-    return _recap(rel, out_cap)
+    return _recap(rel, out_cap, B.OVF_PAIR)
 
 
 # ---------------------------------------------------------------------- #
@@ -307,8 +307,8 @@ class ClusterOps(B.PlanOps):
     ``ShardedOps._bucket_cap`` (n_shards blocks of ~2x the balanced
     share) — so exchange skew past it trips the sticky flag and rides the
     ordinary double-and-retry ladder, and the jit cache keys stay stable.
-    ``finish`` returns the *local* flag; the coordinator ORs the
-    per-worker flags per lane, which is exactly the psum-reduce of the
+    ``finish`` returns the *local* overflow word; the coordinator ORs
+    the per-worker words per lane, which is exactly the reduction of the
     sharded backend."""
 
     def __init__(self, view: WorkerView, n_vertices: int, n_shards: int,
@@ -356,7 +356,7 @@ class ClusterOps(B.PlanOps):
                            self.rank)
 
     def finish(self, pairs: R.Relation):
-        return pairs, pairs.overflow  # coordinator ORs per-worker flags
+        return pairs, pairs.overflow  # coordinator ORs per-worker words
 
     # ---- the exchange ---- #
 
@@ -366,7 +366,7 @@ class ClusterOps(B.PlanOps):
         prefix to host, bucket with the numpy twin of the device hash,
         swap blocks through the fabric, re-embed sorted on device."""
         cnt = int(rel.count)
-        ovf = bool(rel.overflow)
+        ovf = int(rel.overflow)
         cols = [np.asarray(c[:cnt]) for c in rel.cols]
         rows = (np.stack(cols, axis=1) if cols else
                 np.zeros((0, 0), np.int32)).astype(np.int32, copy=False)
@@ -376,15 +376,15 @@ class ClusterOps(B.PlanOps):
                      for d in range(self.n_shards)]
             rows = np.concatenate(self.fabric.all_to_all(parts))
         buf_cap = 2 * pair_cap
-        if rows.shape[0] > buf_cap:
-            ovf = True
+        if rows.shape[0] > buf_cap:  # the buffer scales with pair_cap
+            ovf |= B.OVF_PAIR
             rows = rows[:buf_cap]
         arity = len(rel.cols)
         buf = np.full((buf_cap, arity), int(R.SENTINEL), np.int32)
         buf[:rows.shape[0]] = rows
         return _k_embed(
             tuple(jnp.asarray(buf[:, j]) for j in range(arity)),
-            jnp.asarray(rows.shape[0], R.I32), jnp.asarray(ovf),
+            jnp.asarray(rows.shape[0], R.I32), jnp.asarray(ovf, R.I32),
             unique=unique, out_cap=(pair_cap if recap else buf_cap))
 
 
@@ -397,16 +397,16 @@ def merge_partitions(parts_by_rank: list, n_lanes: int):
     """Merge per-worker partial answers: concat the canonical (globally
     disjoint) partitions in rank order + lexsort ==
     ``ShardedBackend._gather_rows`` == the local engine, bit for bit;
-    per-lane overflow is the OR of the per-worker sticky flags (the
-    queue-world psum)."""
+    per-lane overflow is the OR of the per-worker overflow words (the
+    queue-world reduction)."""
     results: list = [None] * n_lanes
-    overflow = np.zeros(n_lanes, bool)
+    overflow = np.zeros(n_lanes, np.int32)
     for lane in range(n_lanes):
         chunks = []
         for part in parts_by_rank:
             rows, ovf = part[lane]
             if ovf:
-                overflow[lane] = True
+                overflow[lane] |= ovf
             elif rows is not None:
                 chunks.append(rows)
         if not overflow[lane]:
@@ -524,14 +524,14 @@ class WorkerState:
             ops = ClusterOps(self.view, self.n_vertices, self.n_shards,
                              self.rank, self.fabric)
             rel, ovf = B.run_plan_ops(ops, shape, caps, ranges[lane])
-            if bool(ovf):
-                out.append((None, True))
+            if int(ovf):
+                out.append((None, int(ovf)))
             else:
                 cnt = int(rel.count)
                 rows = np.stack([np.asarray(rel.cols[0][:cnt]),
                                  np.asarray(rel.cols[1][:cnt])],
                                 axis=1).astype(np.int32, copy=False)
-                out.append((rows, False))
+                out.append((rows, 0))
         return out
 
 
@@ -565,6 +565,15 @@ class ClusterRuntime:
                  ilog_keep: int = 8):
         if n_workers < 1:
             raise ValueError("n_workers must be >= 1")
+        backend = jax.default_backend()
+        if backend != "cpu":
+            # a chip belongs to one process: this one already holds it, so
+            # a worker would fail or wait out the spawn/reply timeouts
+            raise ClusterError(
+                f"ClusterRuntime spawns worker processes that each need "
+                f"the {backend} devices, which this process already holds; "
+                f"on an accelerator serve in one process with "
+                f"Engine(index) or Engine(index, mesh=...)")
         self.n_shards = int(n_workers)
         # the peer-exchange matrix is plumbed into worker processes at
         # spawn, so the elastic ceiling is fixed up front; default to 2x
@@ -707,7 +716,7 @@ class ClusterRuntime:
 
     def execute(self, shape, caps, ranges: np.ndarray):
         """Synchronous batch: broadcast EXECUTE_BATCH, merge per-worker
-        partitions.  Returns (list of rows-or-None per lane, (B,) bool
+        partitions.  Returns (list of rows-or-None per lane, (B,) int
         overflow) — the ``ExecutionBackend.run_batch`` contract."""
         ranges = np.asarray(ranges, np.int32)
         payload = {"shape": shape, "caps": caps, "ranges": ranges}
@@ -1003,7 +1012,7 @@ class ClusterBackend(B.ExecutionBackend):
     def run(self, shape, caps: B.QueryCaps, ranges: np.ndarray):
         results, ovf = self.runtime.execute(
             shape, caps, np.asarray(ranges, np.int32)[None])
-        return results[0], bool(ovf[0])
+        return results[0], int(ovf[0])
 
     def run_batch(self, shape, caps: B.QueryCaps, ranges: np.ndarray):
         return self.runtime.execute(shape, caps, ranges)

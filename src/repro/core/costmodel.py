@@ -19,9 +19,8 @@ versioned JSON artifact holding
   union executable's per-step overhead, fitted by least squares from the
   micro-calibration harness (:func:`calibrate`) which times each
   operator at a grid of capacity rungs;
-* **autotuned Pallas block shapes** per (capacity rung, dtype) — the
-  winners of :mod:`repro.kernels.autotune`'s sweep, read back by
-  ``kernels/ops.py`` once the table is :func:`activate`\\ d;
+* the **VMEM ceiling** the Pallas kernel wrappers (``kernels/ops.py``)
+  read once the table is :func:`activate`\\ d;
 * a **global calibration scale** corrected online: real traffic
   (:func:`refine_with_engine`, driven by ``Engine.telemetry``) and the
   CI ``BENCH_*.json`` trajectory (:func:`DeviceCostTable.
@@ -38,7 +37,7 @@ see ``core.backend``).
 
 Consumers: ``optimizer.estimate_plan``/``optimize_query`` (cost_ns
 channel), ``Engine.estimate_caps`` (minimal expected-cost rung
-selection), ``kernels/ops.py`` (tuned block shapes + the VMEM ceiling),
+selection), ``kernels/ops.py`` (the VMEM ceiling),
 and ``core.lifecycle`` (the table rides service checkpoints as one
 uint8 leaf).
 
@@ -117,9 +116,9 @@ def fit_affine(rows, times_ns) -> OpCost:
 
 @dataclasses.dataclass
 class DeviceCostTable:
-    """Fitted stage constants + autotuned kernel block shapes for ONE
-    device kind — the shared artifact the optimizer, the capacity
-    estimator and the kernels all read.
+    """Fitted stage constants + the kernels' VMEM ceiling for ONE device
+    kind — the shared artifact the optimizer, the capacity estimator and
+    the kernel wrappers all read.
 
     ``scale`` is the online-refinement knob: synthetic micro-benchmarks
     overstate fused in-plan stage costs (each is timed as its own
@@ -133,8 +132,6 @@ class DeviceCostTable:
     scale: float = 1.0
     dispatch_floor_ns: float = 0.0  # telemetry-refined per-dispatch floor
     ops: dict = dataclasses.field(default_factory=dict)  # name -> OpCost
-    block_q: dict = dataclasses.field(default_factory=dict)  # rung -> block
-    block_t: dict = dataclasses.field(default_factory=dict)  # rung -> block
     vmem_words: int | None = None
     samples: dict = dataclasses.field(default_factory=dict)  # name -> [[rows, ns]]
 
@@ -170,19 +167,6 @@ class DeviceCostTable:
         risk0 = 1.0 if risky else 0.25
         p = min(1.0, risk0 * max(1.0, float(est_rows)) / max(1, cap))
         return self.plan_dispatch_ns(cap) + p * self.plan_dispatch_ns(2 * cap)
-
-    # ---- autotuned kernel blocks ---- #
-
-    def tuned_block(self, kind: str, rung: int) -> int | None:
-        """Winner block for ``kind`` in {"block_q", "block_t"} at the
-        smallest tuned rung >= ``rung`` (capacities quantize onto the
-        pow2 ladder, so the next rung up is the right neighbor); None
-        when nothing relevant was tuned."""
-        table = self.block_q if kind == "block_q" else self.block_t
-        if not table:
-            return None
-        geq = [r for r in table if r >= rung]
-        return table[min(geq)] if geq else table[max(table)]
 
     # ---- online refinement ---- #
 
@@ -259,8 +243,6 @@ class DeviceCostTable:
             "dispatch_floor_ns": self.dispatch_floor_ns,
             "ops": {k: [v.fixed_ns, v.per_row_ns]
                     for k, v in sorted(self.ops.items())},
-            "block_q": {str(r): b for r, b in sorted(self.block_q.items())},
-            "block_t": {str(r): b for r, b in sorted(self.block_t.items())},
             "vmem_words": self.vmem_words,
             "samples": {k: v for k, v in sorted(self.samples.items())},
         }
@@ -279,10 +261,6 @@ class DeviceCostTable:
             dispatch_floor_ns=float(payload.get("dispatch_floor_ns", 0.0)),
             ops={k: OpCost(float(a), float(b))
                  for k, (a, b) in payload.get("ops", {}).items()},
-            block_q={int(r): int(b)
-                     for r, b in payload.get("block_q", {}).items()},
-            block_t={int(r): int(b)
-                     for r, b in payload.get("block_t", {}).items()},
             vmem_words=(None if payload.get("vmem_words") is None
                         else int(payload["vmem_words"])),
             samples={k: [[float(r), float(t)] for r, t in v]
@@ -313,19 +291,14 @@ class DeviceCostTable:
 
 
 def activate(table: DeviceCostTable | None) -> None:
-    """Install (or, with None, uninstall) the table's kernel-facing
-    halves — tuned block shapes and the VMEM ceiling override — into
-    ``repro.kernels.ops``.  Pricing stays explicit (tables are passed to
-    engines), but kernels are called from inside jitted plan walkers, so
-    their tuning rides a process-wide registry."""
+    """Install (or, with None, uninstall) the table's kernel-facing half —
+    the VMEM ceiling override — into ``repro.kernels.ops``.  Pricing
+    stays explicit (tables are passed to engines), but kernels are called
+    from inside jitted programs, so their ceiling rides a process-wide
+    registry."""
     from repro.kernels import ops as kops  # lazy: host-only module otherwise
 
-    if table is None:
-        kops.set_tuned_blocks(None, None)
-        kops.set_vmem_words_override(None)
-        return
-    kops.set_tuned_blocks(dict(table.block_q), dict(table.block_t))
-    kops.set_vmem_words_override(table.vmem_words)
+    kops.set_vmem_words_override(None if table is None else table.vmem_words)
 
 
 # ---------------------------------------------------------------------- #
@@ -340,8 +313,8 @@ DEFAULT_RUNGS = (256, 1024, 4096)
 def ladder_rungs(engine, queries=(), max_rungs: int = 4) -> list[int]:
     """The pow2 capacity rungs this engine actually starts plans at:
     the estimated ``pair_cap`` of each probe query plus the worst-case
-    default — the grid the calibration and the block-shape sweeps key
-    on, so the table prices the rungs real traffic dispatches."""
+    default — the grid the calibration keys on, so the table prices the
+    rungs real traffic dispatches."""
     from .query import plan_shape
 
     rungs = {int(engine._default_caps.pair_cap)}
@@ -418,9 +391,8 @@ def calibrate(rungs=None, repeats: int = 3, n_vertices: int = 1 << 16,
     for r in rungs:
         ops = LocalOps(arrays_for(r), min(n_vertices, r))
         ids = jnp.arange(r, dtype=R.I32)
-        rel1 = R.Relation((ids,), jnp.asarray(r, R.I32), jnp.asarray(False))
-        pairs = R.Relation((ids, ids), jnp.asarray(r, R.I32),
-                           jnp.asarray(False))
+        rel1 = R.Relation((ids,), jnp.asarray(r, R.I32), jnp.int32(0))
+        pairs = R.Relation((ids, ids), jnp.asarray(r, R.I32), jnp.int32(0))
 
         timed = {
             "lookup": jax.jit(

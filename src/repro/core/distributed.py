@@ -22,8 +22,8 @@ The fixed bucket capacity is the static-shape contract: each exchange
 moves (n_shards, bucket_cap, arity) per shard; overflow is flagged and
 resolved by the ONE overflow-ladder contract specified in the
 ``core.backend`` module docstring — this module adds nothing to it
-beyond psum-reducing the per-shard sticky flags so every shard and the
-host agree on a retry.
+beyond OR-reducing the per-shard sticky overflow words so every shard
+and the host agree on a retry.
 
 Whole-plan execution
 --------------------
@@ -34,8 +34,8 @@ the mesh axis, against :class:`ShardedOps` — class-space relations
 replicated, pair-space relations hash-partitioned by source vertex (the
 canonical distribution: conjunctions and identity filters are then
 exchange-free; a join repartitions its probe side by the join key and
-its output back to canonical).  Per-shard sticky overflow flags are
-psum-reduced so every shard — and the host — agrees on retry.
+its output back to canonical).  Per-shard sticky overflow words are
+OR-reduced so every shard — and the host — agrees on retry.
 
 Planning (and the cost-based optimizer) stays a host concern: the
 backend carries the replicated :class:`~repro.core.stats.IndexStats`
@@ -62,6 +62,7 @@ from .sharded_index import (
     ShardedIndexArrays,
     index_specs,
     partition_rows,
+    place_index,
     replicated_stats,
     shard_index,
 )
@@ -79,10 +80,11 @@ def _bucket_of(key: jax.Array, n_shards: int) -> jax.Array:
 
 
 def _pack_buckets(cols: tuple, valid: jax.Array, bucket: jax.Array,
-                  n_shards: int, bucket_cap: int):
+                  n_shards: int, bucket_cap: int, bit: int = 1):
     """Arrange local rows into (n_shards, bucket_cap, arity) by bucket —
     sort by bucket then slot-gather (the MoE-dispatch pattern: no
-    scatter).  Returns (packed cols tuple, per-bucket counts, overflow)."""
+    scatter).  Returns (packed cols tuple, per-bucket counts, overflow
+    word: ``bit`` when a bucket exceeded ``bucket_cap``)."""
     cap = cols[0].shape[0]
     bkey = jnp.where(valid, bucket, n_shards)  # invalid -> trash bucket
     order = jax.lax.sort((bkey, jnp.arange(cap, dtype=I32)), num_keys=1,
@@ -94,7 +96,7 @@ def _pack_buckets(cols: tuple, valid: jax.Array, bucket: jax.Array,
     ends = jnp.searchsorted(sorted_b, jnp.arange(n_shards, dtype=I32),
                             side="right").astype(I32)
     sizes = ends - offs
-    overflow = jnp.any(sizes > bucket_cap)
+    overflow = R.overflow_bit(jnp.any(sizes > bucket_cap), bit)
     b = jnp.arange(n_shards * bucket_cap, dtype=I32) // bucket_cap
     slot = jnp.arange(n_shards * bucket_cap, dtype=I32) % bucket_cap
     src = jnp.clip(offs[b] + slot, 0, cap - 1)
@@ -124,7 +126,7 @@ def _flatten_received(received: tuple, counts: jax.Array):
     flat = tuple(c.reshape(-1) for c in received)
     total = jnp.sum(counts)
     rel = R.Relation(flat, jnp.asarray(flat[0].shape[0], I32),
-                     jnp.asarray(False))
+                     jnp.int32(0))
     # SENTINEL-padded rows inside each block sort to the end
     rel = R.rel_sort(rel)
     return R.Relation(rel.cols, total.astype(I32), rel.overflow)
@@ -136,13 +138,14 @@ def _flatten_received(received: tuple, counts: jax.Array):
 
 
 def repartition(cols: tuple, count: jax.Array, key_col: int, n_shards: int,
-                bucket_cap: int, axis: str):
+                bucket_cap: int, axis: str, bit: int = 1):
     """Move every row to the shard owning hash(key).  Local view in/out.
-    Returns (cols, count, overflow) with capacity n_shards*bucket_cap."""
+    Returns (cols, count, overflow) with capacity n_shards*bucket_cap; a
+    block past ``bucket_cap`` sets ``bit`` in the overflow word."""
     valid = jnp.arange(cols[0].shape[0], dtype=I32) < count
     bucket = _bucket_of(cols[key_col], n_shards)
     packed, sizes, ovf = _pack_buckets(cols, valid, bucket, n_shards,
-                                       bucket_cap)
+                                       bucket_cap, bit)
     received, cnt = _exchange(packed, sizes, axis)
     rel = _flatten_received(received, cnt)
     return rel.cols, rel.count, ovf | rel.overflow
@@ -155,8 +158,8 @@ def sharded_join_local(a_cols, a_count, b_cols, b_count, out_cap: int,
     the build-side sort when the producer already emits sorted rows
     (repartition's _flatten_received does — §Perf iteration: the double
     sort was ~40% of the join's local traffic)."""
-    a = R.Relation(a_cols, a_count, jnp.asarray(False))
-    b = R.Relation(b_cols, b_count, jnp.asarray(False))
+    a = R.Relation(a_cols, a_count, jnp.int32(0))
+    b = R.Relation(b_cols, b_count, jnp.int32(0))
     if not b_sorted:
         b = R.rel_sort(b)
     out_cols = [("a", 0), ("b", 1)] + [("a", j) for j in range(2, len(a_cols))] \
@@ -246,12 +249,12 @@ def make_distributed_query_step(mesh, axis: str):
         c2p_cls, c2p_v, c2p_u = c2p_cls[0], c2p_v[0], c2p_u[0]
         n = c2p_count[0]
         ra = R.Relation((ca,), jnp.sum(ca != R.SENTINEL).astype(I32),
-                        jnp.asarray(False))
+                        jnp.int32(0))
         rb = R.Relation((cb,), jnp.sum(cb != R.SENTINEL).astype(I32),
-                        jnp.asarray(False))
+                        jnp.int32(0))
         inter = R.rel_intersect(ra, rb, 1)
         # local materialize: my slice of c2p filtered to surviving classes
-        local = R.Relation((c2p_cls, c2p_v, c2p_u), n, jnp.asarray(False))
+        local = R.Relation((c2p_cls, c2p_v, c2p_u), n, jnp.int32(0))
         keep = R.lex_count_matches((inter.cols[0],), (c2p_cls,),
                                    inter.count) > 0
         out = R.rel_compact(local, keep)
@@ -317,12 +320,14 @@ class ShardedOps(B.PlanOps):
         """Repartition a pair relation by hash(v) and re-embed at
         ``pair_cap`` (exchange skew past a block or pair_cap trips the
         sticky flag)."""
+        # exchange blocks scale with pair_cap: their overflow is its
         cols, cnt, ovf = repartition(rel.cols, rel.count, 0, self.n_shards,
-                                     self._bucket_cap(pair_cap), self.axis)
+                                     self._bucket_cap(pair_cap), self.axis,
+                                     B.OVF_PAIR)
         out = R.Relation(cols, cnt, rel.overflow | ovf)
         if unique:
             out = R.rel_unique(out)
-        return _recap(out, pair_cap)
+        return _recap(out, pair_cap, B.OVF_PAIR)
 
     def materialize(self, classes: R.Relation, pair_cap: int) -> R.Relation:
         local = super().materialize(classes, pair_cap)  # my classes only
@@ -333,7 +338,8 @@ class ShardedOps(B.PlanOps):
         # probe side to the shard owning its join key u; the build side
         # is canonical — already partitioned by its key v
         ac, an, ovf = repartition(a.cols, a.count, 1, self.n_shards,
-                                  self._bucket_cap(pair_cap), self.axis)
+                                  self._bucket_cap(pair_cap), self.axis,
+                                  B.OVF_PAIR)
         a2 = R.Relation(ac, an, a.overflow | ovf)
         out = B._join_pairs(a2, b, join_cap, pair_cap)
         return self._canonical(out, pair_cap, unique=True)
@@ -345,10 +351,34 @@ class ShardedOps(B.PlanOps):
         return R.rel_compact(base, mine)
 
     def finish(self, pairs: R.Relation):
-        # every shard's sticky flag counts: reduce so the host (and all
-        # shards) agree on retry with one scalar read
-        ovf = jax.lax.psum(pairs.overflow.astype(I32), self.axis) > 0
+        # every shard's overflow bits count: OR them over the shards (a
+        # max per bit) so the host and all shards agree on the retry
+        bits = jnp.asarray((B.OVF_CLASS, B.OVF_PAIR, B.OVF_JOIN), I32)
+        ovf = jnp.sum(jax.lax.pmax(pairs.overflow & bits, self.axis))
         return pairs, ovf
+
+
+def sharded_run_plan(mesh, axis: str, n_vertices: int, shape,
+                     caps: B.QueryCaps):
+    """The plan walker of ``shape`` at ``caps`` inside one ``shard_map``
+    over ``mesh``'s ``axis``, jitted: (ShardedIndexArrays, (n_lookups, 2)
+    ranges) -> ((v, u) cols, counts, overflow words), one row per shard."""
+    n_shards = int(dict(mesh.shape)[axis])
+    specs = index_specs(axis)
+
+    def body(arrs: ShardedIndexArrays, ranges):
+        local = ShardedIndexArrays(*[
+            leaf[0] if spec == P(axis) else leaf
+            for leaf, spec in zip(arrs, specs)])
+        ops = ShardedOps(local, n_vertices, n_shards, axis)
+        pairs, ovf = B.run_plan_ops(ops, shape, caps, ranges)
+        return (tuple(c[None] for c in pairs.cols), pairs.count[None],
+                ovf[None])
+
+    sh = P(axis)
+    return jax.jit(compat.shard_map(
+        body, mesh=mesh, in_specs=(specs, P()),
+        out_specs=((sh, sh), sh, sh)))
 
 
 class ShardedBackend(B.ExecutionBackend):
@@ -367,14 +397,13 @@ class ShardedBackend(B.ExecutionBackend):
             raise ValueError(
                 f"index sharded {sharded.n_shards}-way but mesh axis "
                 f"{axis!r} has {n_mesh} devices")
-        self.sharded = sharded
+        self.sharded = place_index(sharded, mesh, axis)
         self.mesh = mesh
         self.axis = axis
         self.n_vertices = n_vertices
         self.n_shards = sharded.n_shards
         self.k = k
         self._stats = None  # lazy: see the `stats` property
-        self._specs = index_specs(axis)
         self._cache: dict = {}
 
     @property
@@ -427,7 +456,8 @@ class ShardedBackend(B.ExecutionBackend):
         must drop only when ``n_vertices`` moves — it is baked into the
         traced bodies (IDENTITY).  The replicated statistics view is
         invalidated with the arrays, mirroring ``Engine.rebind``."""
-        self.sharded = shard_index(index, self.n_shards)
+        self.sharded = place_index(shard_index(index, self.n_shards),
+                                   self.mesh, self.axis)
         self.k = index.k
         self._stats = None
         if index.n_vertices != self.n_vertices:
@@ -437,34 +467,24 @@ class ShardedBackend(B.ExecutionBackend):
     def _compiled(self, shape, caps):
         key = (shape, caps)
         fn = self._cache.get(key)
-        if fn is not None:
-            return fn
-        n_shards, axis, n_vertices = self.n_shards, self.axis, self.n_vertices
-        specs = self._specs
-
-        def body(arrs: ShardedIndexArrays, ranges):
-            local = ShardedIndexArrays(*[
-                leaf[0] if spec == P(axis) else leaf
-                for leaf, spec in zip(arrs, specs)])
-            ops = ShardedOps(local, n_vertices, n_shards, axis)
-            pairs, ovf = B.run_plan_ops(ops, shape, caps, ranges)
-            return (tuple(c[None] for c in pairs.cols), pairs.count[None],
-                    ovf[None])
-
-        sh = P(axis)
-        fn = jax.jit(compat.shard_map(
-            body, mesh=self.mesh, in_specs=(specs, P()),
-            out_specs=((sh, sh), sh, sh)))
-        self._cache[key] = fn
+        if fn is None:
+            fn = self._cache[key] = sharded_run_plan(
+                self.mesh, self.axis, self.n_vertices, shape, caps)
         return fn
 
     def run(self, shape, caps: B.QueryCaps, ranges: np.ndarray):
         fn = self._compiled(shape, caps)
         with compat.set_mesh(self.mesh):
             cols, counts, ovf = fn(self.sharded, jnp.asarray(ranges))
-        if np.asarray(ovf).any():
-            return None, True
-        return self._gather_rows(cols, counts), False
+        kinds = int(np.bitwise_or.reduce(np.asarray(ovf).ravel()))
+        if kinds:
+            return None, kinds
+        return self._gather_rows(cols, counts), 0
+
+    def compile_batch(self, shape, caps: B.QueryCaps, ranges: np.ndarray):
+        with compat.set_mesh(self.mesh):
+            return self._compiled(shape, caps).lower(
+                self.sharded, jnp.asarray(ranges[0])).compile()
 
     def run_batch(self, shape, caps: B.QueryCaps, ranges: np.ndarray):
         # lanes share one compiled executable; each dispatches its own
@@ -474,7 +494,7 @@ class ShardedBackend(B.ExecutionBackend):
             rows, ovf = self.run(shape, caps, ranges[lane])
             results.append(rows)
             overflow.append(ovf)
-        return results, np.asarray(overflow, bool)
+        return results, np.asarray(overflow, np.int32)
 
     def _gather_rows(self, cols, counts) -> np.ndarray:
         v, u = np.asarray(cols[0]), np.asarray(cols[1])

@@ -30,6 +30,9 @@ import numpy as np
 
 from .backend import (  # noqa: F401  (QueryCaps/run_plan* are public API)
     OP_NOP,
+    OVF_CLASS,
+    OVF_JOIN,
+    OVF_PAIR,
     ExecutionBackend,
     LocalBackend,
     QueryCaps,
@@ -318,7 +321,7 @@ class Engine:
             if not overflow:
                 return rows
             self.telemetry.retry_rungs += 1
-            caps = self._escalate(caps, attempt)
+            caps = self._escalate(caps, attempt, int(overflow))
             if attempt >= 3:
                 self.telemetry.default_jumps += 1
         raise RuntimeError("query overflow not resolved after retries")
@@ -340,25 +343,32 @@ class Engine:
         return evaluate(self, q, srcs=srcs, dsts=dsts,
                         n_labels=n_labels, info=info)
 
-    def _escalate(self, caps: QueryCaps, attempt: int) -> QueryCaps:
+    def _escalate(self, caps: QueryCaps, attempt: int,
+                  kinds: int) -> QueryCaps:
         """Overflow-retry schedule (the host half of the ladder contract
-        in the ``core.backend`` docstring): double, and after a few
-        failed attempts from a (possibly far-too-tight) estimate jump to
-        at least the worst-case default so the ladder can't exhaust
-        below the caps the pre-estimator engine would have started from.
+        in the ``core.backend`` docstring): double the capacities named
+        by ``kinds`` (``OVF_*`` bits; the others were large enough), and
+        after a few failed attempts from a (possibly far-too-tight)
+        estimate jump them to at least the worst-case default so the
+        ladder can't exhaust below the caps the pre-estimator engine
+        would have started from.  Growing only the overflowed kinds keeps
+        a generously estimated join capacity from doubling along with an
+        undersized pair capacity until it outgrows device memory.
         Early rungs are cheap (small executables), the default rung is
         not — so the jump waits for three doublings, which lets a mildly
         undersized estimate land on a right-sized rung instead of paying
         the worst-case dispatch.  (The default ``max_retries`` is 10 so
         the reachable ceiling past the jump — default x 2^6 — matches
         the pre-optimizer schedule's.)"""
-        caps = caps.doubled()
-        if attempt >= 3:
-            d = self._default_caps
-            caps = QueryCaps(max(caps.class_cap, d.class_cap),
-                             max(caps.pair_cap, d.pair_cap),
-                             max(caps.join_cap, d.join_cap))
-        return caps
+        d = self._default_caps
+        grown = []
+        for bit, cap, dflt in ((OVF_CLASS, caps.class_cap, d.class_cap),
+                               (OVF_PAIR, caps.pair_cap, d.pair_cap),
+                               (OVF_JOIN, caps.join_cap, d.join_cap)):
+            if kinds & bit:
+                cap = max(2 * cap, dflt) if attempt >= 3 else 2 * cap
+            grown.append(cap)
+        return QueryCaps(*grown)
 
     def execute_batch(self, queries, caps: QueryCaps | None = None,
                       max_retries: int = 10, plans: list | None = None,
@@ -396,6 +406,21 @@ class Engine:
         skip re-planning; must align with ``queries``."""
         if not queries:
             return BatchHandle(results=[], groups=[])
+        groups = self.group_batch(queries, caps=caps, plans=plans,
+                                  min_bucket=min_bucket, union=union)
+        self.telemetry.queries += len(queries)
+        for g in groups:
+            self.telemetry.dispatches += 1
+            g.handle = self._dispatch_group(g)
+        return BatchHandle(results=[None] * len(queries), groups=groups)
+
+    def group_batch(self, queries, caps: QueryCaps | None = None,
+                    plans: list | None = None, min_bucket: int = 4,
+                    union: bool = False) -> list:
+        """The dispatch units :meth:`dispatch_batch` would run for
+        ``queries`` (same arguments), planned and bucketed but not
+        dispatched — each is one executable, so a caller can compile
+        them ahead of traffic."""
         if plans is None:
             plans = [self.plan(q) for q in queries]
         all_ranges = [self.lookup_ranges(p) for p in plans]
@@ -438,12 +463,7 @@ class Engine:
                   for shape, c, m in work]
         if union and self.backend.supports_union:
             groups = self._fuse_stragglers(groups, all_ranges, min_bucket)
-
-        self.telemetry.queries += len(queries)
-        for g in groups:
-            self.telemetry.dispatches += 1
-            g.handle = self._dispatch_group(g)
-        return BatchHandle(results=[None] * len(queries), groups=groups)
+        return groups
 
     def _fuse_stragglers(self, groups: list, all_ranges: list,
                          min_bucket: int) -> list:
@@ -506,22 +526,27 @@ class Engine:
                 for lane, r in enumerate(rows):
                     if r is not None:
                         results[pending[lane]] = r
-                if not overflow.any():
+                overflow = np.asarray(overflow)
+                tripped = overflow != 0
+                if not tripped.any():
                     break
-                # only the lanes whose own flag tripped climb a rung
-                self.telemetry.retry_rungs += int(overflow.sum())
+                # only the lanes whose own flag tripped climb a rung, by
+                # the capacities any of them overflowed
+                self.telemetry.retry_rungs += int(tripped.sum())
                 if attempt >= 3:
-                    self.telemetry.default_jumps += int(overflow.sum())
-                grp_caps = self._escalate(grp_caps, attempt)
+                    self.telemetry.default_jumps += int(tripped.sum())
+                grp_caps = self._escalate(
+                    grp_caps, attempt,
+                    int(np.bitwise_or.reduce(overflow[tripped])))
                 attempt += 1
                 if attempt >= max_retries:
                     raise RuntimeError(
                         "query overflow not resolved after retries")
-                pending = pending[overflow]
-                ranges = ranges[overflow]
+                pending = pending[tripped]
+                ranges = ranges[tripped]
                 self.telemetry.dispatches += 1
                 if opcodes is not None:
-                    opcodes = opcodes[overflow]
+                    opcodes = opcodes[tripped]
                     rows, overflow = self.backend.run_union_batch(
                         opcodes, grp_caps, g.stack_size, ranges)
                 else:

@@ -301,7 +301,7 @@ def from_host_mirror(
         seq_starts=jnp.asarray(seq_starts), seq_ends=jnp.asarray(seq_ends),
         l2c_cls=jnp.asarray(l2c_cls),
         l2c_count=jnp.asarray(n_l2c, R.I32),
-        overflow=jnp.asarray(False),
+        overflow=jnp.int32(0),
     )
     return CPQxIndex(
         k=k, n_vertices=n_vertices, arrays=arrays, seq_ranges=seq_ranges,

@@ -105,7 +105,7 @@ def _recap_rel(rel: R.Relation, cap: int) -> R.Relation:
     src = jnp.clip(idx, 0, rel.capacity - 1)
     cols = tuple(jnp.where(m, c[src], R.SENTINEL) for c in rel.cols)
     return R.Relation(cols, jnp.minimum(rel.count, cap).astype(R.I32),
-                      rel.overflow | (rel.count > cap))
+                      rel.overflow | R.overflow_bit(rel.count > cap))
 
 
 def build_interest(g: LabeledGraph, k: int, interests: Iterable[tuple],

@@ -16,7 +16,7 @@ from the ``I_c2p`` CSR offsets):
   run that fits one indexed segment is always taken whole: its
   materialization *is* the answer, so no split can beat it.
 * **conjunction ordering** — CONJ is commutative; operands are ordered
-  smallest-estimate-first so the sorted-intersect kernel probes the
+  smallest-estimate-first so the sorted class-list intersection probes the
   small side and intermediate caps track the selective operand.
 * **join association** — composition is associative; flattened join
   chains are re-associated by an interval DP (matrix-chain style) over

@@ -76,15 +76,16 @@ def enumerate_path_levels(dg: DeviceGraph, k: int, caps: tuple) -> tuple:
     return tuple(levels)
 
 
-def _recap(rel: R.Relation, cap: int) -> R.Relation:
-    """Re-embed a relation at a (>= count) capacity."""
+def _recap(rel: R.Relation, cap: int, bit: int = 1) -> R.Relation:
+    """Re-embed a relation at a (>= count) capacity; more rows than
+    ``cap`` set ``bit`` in the overflow."""
     if rel.capacity == cap:
         return rel
     idx = jnp.arange(cap, dtype=R.I32)
     m = idx < rel.count
     src = jnp.clip(idx, 0, rel.capacity - 1)
     cols = tuple(jnp.where(m, c[src], R.SENTINEL) for c in rel.cols)
-    overflow = rel.overflow | (rel.count > cap)
+    overflow = rel.overflow | R.overflow_bit(rel.count > cap, bit)
     return R.Relation(cols, jnp.minimum(rel.count, cap).astype(R.I32), overflow)
 
 

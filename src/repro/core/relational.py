@@ -11,10 +11,12 @@ capacities with the numpy estimator and retries on overflow.
 
 Design notes (hardware adaptation, see DESIGN.md §2):
 
-* multi-column lexicographic sort  -> one ``jax.lax.sort`` with num_keys
+* multi-column lexicographic sort  -> stable two-operand sorts, one per
+  key, least significant first (``lex_argsort``), then gathers
 * pointer-walk merge join          -> branch-free *vectorized binary
   search* (fixed trip count = bit-length of capacity) + capacity-padded
-  expansion join (cumsum + searchsorted row recovery)
+  expansion join (prefix sum + searchsorted row recovery)
+* compaction (filter)              -> prefix sum + searchsorted + gather
 * hash maps                        -> dense ranks (exact, collision-free)
 * per-pair signature sets          -> order-invariant two-lane uint32
   fingerprints (sum of avalanche-mixed rows after exact dedup)
@@ -41,7 +43,12 @@ class Relation(NamedTuple):
 
     ``cols``     tuple of (cap,) int32 arrays; rows >= count are SENTINEL.
     ``count``    scalar int32 — number of valid rows.
-    ``overflow`` scalar bool — sticky flag: some producer dropped rows.
+    ``overflow`` scalar int32 — sticky overflow word: 0, or the bits of
+                 the capacities some producer exceeded (it dropped rows).
+                 Operators OR their inputs' words; an operator with an
+                 output capacity sets the bit its caller names (1 unless
+                 the caller attributes capacities, as the query engine's
+                 ``OVF_*`` bits do).
     """
 
     cols: tuple
@@ -62,8 +69,14 @@ def make_relation(cols: Sequence[jax.Array], count=None, overflow=None) -> Relat
     if count is None:
         count = jnp.asarray(cols[0].shape[0], I32)
     if overflow is None:
-        overflow = jnp.asarray(False)
-    return Relation(cols, jnp.asarray(count, I32), jnp.asarray(overflow))
+        overflow = 0
+    return Relation(cols, jnp.asarray(count, I32), jnp.asarray(overflow, I32))
+
+
+def overflow_bit(exceeded, bit: int = 1) -> jax.Array:
+    """The overflow word of one capacity check: ``bit`` where
+    ``exceeded`` holds, else 0."""
+    return jnp.where(exceeded, jnp.int32(bit), jnp.int32(0))
 
 
 def from_numpy(rows: np.ndarray, capacity: int) -> Relation:
@@ -112,25 +125,78 @@ def batch_to_numpy(rel: Relation, lanes=None) -> list[np.ndarray]:
 # ---------------------------------------------------------------------- #
 
 
+def lex_argsort(keys: Sequence[jax.Array]) -> jax.Array:
+    """Stable lexicographic argsort of parallel key columns.
+
+    One stable two-operand ``(key, permutation)`` sort per key, least
+    significant key first, inside a ``fori_loop`` so the program holds a
+    single sort.  The TPU compiler's time grows steeply with a sort's
+    operands and keys (a five-key sort of a million rows takes it over
+    ten minutes, one two-operand sort well under one), and a plan holds
+    several sorts, so this keeps compile time bounded."""
+    n = keys[0].shape[0]
+    perm = jnp.arange(n, dtype=I32)
+    if len(keys) == 1:
+        return jax.lax.sort((keys[0], perm), num_keys=1, is_stable=True)[1]
+    stacked = jnp.stack(keys)
+
+    def one_key(i, perm):
+        key = stacked[len(keys) - 1 - i][perm]
+        return jax.lax.sort((key, perm), num_keys=1, is_stable=True)[1]
+
+    return jax.lax.fori_loop(0, len(keys), one_key, perm)
+
+
 def rel_sort(rel: Relation, num_keys: int | None = None) -> Relation:
-    """Sort rows lexicographically by the first ``num_keys`` columns.
-    SENTINEL padding rows sort to the end (values < SENTINEL invariant)."""
+    """Sort rows lexicographically by the first ``num_keys`` columns
+    (stable).  SENTINEL padding rows sort to the end (values < SENTINEL
+    invariant)."""
     nk = num_keys if num_keys is not None else rel.arity
-    sorted_cols = jax.lax.sort(rel.cols, num_keys=nk, is_stable=True)
-    return Relation(tuple(sorted_cols), rel.count, rel.overflow)
+    if nk == 1 and rel.arity <= 2:
+        sorted_cols = jax.lax.sort(rel.cols, num_keys=1, is_stable=True)
+        return Relation(tuple(sorted_cols), rel.count, rel.overflow)
+    perm = lex_argsort(rel.cols[:nk])
+    return Relation(tuple(c[perm] for c in rel.cols), rel.count,
+                    rel.overflow)
+
+
+#: Row width of :func:`prefix_sum`'s tiles.
+_SCAN_BLOCK = 1024
+
+
+def prefix_sum(x: jax.Array) -> jax.Array:
+    """Inclusive int32 prefix sum of a 1-D array — the values of
+    ``jnp.cumsum`` (wrapping int32 addition is associative), computed as
+    a row-wise cumsum over ``(n / 1024, 1024)`` tiles plus the running
+    row offsets.  The TPU compiler builds this in seconds, where one flat
+    ``cumsum`` of a million elements takes it tens of seconds."""
+    n = x.shape[0]
+    x = x.astype(I32)
+    if n <= _SCAN_BLOCK:
+        return jnp.cumsum(x, dtype=I32)
+    rows = -(-n // _SCAN_BLOCK)
+    pad = rows * _SCAN_BLOCK - n
+    tiles = jnp.pad(x, (0, pad)).reshape(rows, _SCAN_BLOCK)
+    inner = jnp.cumsum(tiles, axis=1, dtype=I32)
+    totals = inner[:, -1]
+    offsets = prefix_sum(totals) - totals
+    return (inner + offsets[:, None]).reshape(-1)[:n]
 
 
 def rel_compact(rel: Relation, keep: jax.Array) -> Relation:
     """Stable-move rows with keep=True to the front; drop the rest.
 
-    Implemented as a stable sort on the boolean key — branch-free, no
-    scatter."""
+    Gathers only, no sort and no scatter: output row ``j`` is the first
+    row whose running keep-count exceeds ``j`` (one binary search over
+    the prefix sum)."""
     keep = keep & valid_mask(rel)
-    key = jnp.where(keep, jnp.int32(0), jnp.int32(1))
-    out = jax.lax.sort((key,) + rel.cols, num_keys=1, is_stable=True)
-    new_count = jnp.sum(keep, dtype=I32)
-    m = jnp.arange(rel.capacity, dtype=I32) < new_count
-    cols = tuple(jnp.where(m, c, SENTINEL) for c in out[1:])
+    csum = prefix_sum(keep)
+    new_count = csum[-1]
+    j = jnp.arange(rel.capacity, dtype=I32)
+    src = jnp.clip(jnp.searchsorted(csum, j, side="right"), 0,
+                   rel.capacity - 1)
+    m = j < new_count
+    cols = tuple(jnp.where(m, c[src], SENTINEL) for c in rel.cols)
     return Relation(cols, new_count, rel.overflow)
 
 
@@ -156,7 +222,7 @@ def dense_rank(rel: Relation, num_keys: int | None = None):
     n_unique int32).  Exact — no hashing."""
     nk = num_keys if num_keys is not None else rel.arity
     first = _new_group_mask(rel.cols[:nk]) & valid_mask(rel)
-    ranks = jnp.cumsum(first.astype(I32)) - 1
+    ranks = prefix_sum(first) - 1
     n_unique = jnp.sum(first, dtype=I32)
     ranks = jnp.where(valid_mask(rel), ranks, SENTINEL)
     return ranks, n_unique
@@ -250,11 +316,13 @@ def rel_difference(a: Relation, b: Relation, num_keys: int | None = None) -> Rel
     return Relation(out.cols, out.count, out.overflow | b.overflow)
 
 
-def rel_concat(a: Relation, b: Relation, capacity: int) -> Relation:
-    """Union-all into a fresh capacity (rows beyond capacity overflow)."""
+def rel_concat(a: Relation, b: Relation, capacity: int,
+               bit: int = 1) -> Relation:
+    """Union-all into a fresh capacity (rows beyond capacity overflow,
+    setting ``bit``)."""
     assert a.arity == b.arity
     total = a.count + b.count
-    overflow = a.overflow | b.overflow | (total > capacity)
+    overflow = a.overflow | b.overflow | overflow_bit(total > capacity, bit)
     cols = []
     idx = jnp.arange(capacity, dtype=I32)
     for ca, cb in zip(a.cols, b.cols):
@@ -278,11 +346,13 @@ def expansion_join(
     a_on: Sequence[int],
     out_cols: Sequence[tuple],
     out_capacity: int,
+    bit: int = 1,
 ) -> Relation:
     """Join a with b where ``a.cols[a_on] == b.cols[:len(a_on)]``.
 
     ``b`` must be sorted on its first len(a_on) columns.  ``out_cols`` is a
     list of ("a"|"b", col_index) selectors for the output projection.
+    More than ``out_capacity`` output rows set ``bit`` in the overflow.
 
     The classic TPU-native expansion join: per-a-row match counts from two
     binary searches, exclusive cumsum for output offsets, then output-row
@@ -293,24 +363,33 @@ def expansion_join(
     lo = lex_searchsorted(b.cols[:nk], a_keys, "left")
     hi = lex_searchsorted(b.cols[:nk], a_keys, "right")
     cnt = jnp.where(valid_mask(a) & (lo < b.count), hi - lo, 0).astype(I32)
-    ends = jnp.cumsum(cnt, dtype=I32)  # inclusive
-    total = ends[-1] if a.capacity > 0 else jnp.int32(0)
-    starts = ends - cnt
-
-    t = jnp.arange(out_capacity, dtype=I32)
-    # a-row index of output row t: first i with ends[i] > t
-    ai = jnp.searchsorted(ends, t, side="right").astype(I32)
-    ai_c = jnp.clip(ai, 0, a.capacity - 1)
-    bj = lo[ai_c] + (t - starts[ai_c])
+    ai, bj, out_valid, total = expand_ranges(lo, cnt, out_capacity)
     bj = jnp.clip(bj, 0, b.capacity - 1)
-    out_valid = t < total
 
     cols = []
     for which, ci in out_cols:
-        src = a.cols[ci][ai_c] if which == "a" else b.cols[ci][bj]
+        src = a.cols[ci][ai] if which == "a" else b.cols[ci][bj]
         cols.append(jnp.where(out_valid, src, SENTINEL))
-    overflow = a.overflow | b.overflow | (total > out_capacity)
+    overflow = (a.overflow | b.overflow
+                | overflow_bit(total > out_capacity, bit))
     return Relation(tuple(cols), jnp.minimum(total, out_capacity).astype(I32), overflow)
+
+
+def expand_ranges(lo: jax.Array, cnt: jax.Array, out_capacity: int):
+    """Output-row recovery of an expansion: probe row ``i`` owns
+    ``cnt[i]`` consecutive output rows, reading build rows ``lo[i]``,
+    ``lo[i] + 1``, ...  Returns ``(ai, bj, valid, total)``: for each
+    output row ``t < out_capacity`` its probe row (clipped) and build row
+    (unclipped), ``t < total``, and the true output count ``total``."""
+    n = cnt.shape[0]
+    ends = prefix_sum(cnt)  # inclusive
+    total = ends[-1] if n > 0 else jnp.int32(0)
+    starts = ends - cnt
+    t = jnp.arange(out_capacity, dtype=I32)
+    # probe row of output row t: first i with ends[i] > t
+    ai = jnp.clip(jnp.searchsorted(ends, t, side="right").astype(I32), 0,
+                  max(n - 1, 0))
+    return ai, lo[ai] + (t - starts[ai]), t < total, total
 
 
 # ---------------------------------------------------------------------- #

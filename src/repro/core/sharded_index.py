@@ -41,7 +41,7 @@ from typing import NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from . import relational as R
 from .index import CPQxIndex, DeviceIndexArrays
@@ -94,6 +94,17 @@ def index_specs(axis: str) -> ShardedIndexArrays:
         f: (P(axis) if f in _SHARDED_FIELDS else P())
         for f in ShardedIndexArrays._fields
     })
+
+
+def place_index(sharded: ShardedIndexArrays, mesh,
+                axis: str) -> ShardedIndexArrays:
+    """Put the leaves on ``mesh``: each shard of a sharded leaf on its own
+    device along ``axis``, replicated leaves on every device — so a
+    ``shard_map`` call finds every shard where it runs and moves no
+    index bytes."""
+    return ShardedIndexArrays(*[
+        jax.device_put(leaf, NamedSharding(mesh, spec))
+        for leaf, spec in zip(sharded, index_specs(axis))])
 
 
 # ---------------------------------------------------------------------- #
@@ -159,6 +170,14 @@ def _pow2(n: int) -> int:
     return 1 << (max(1, int(n)) - 1).bit_length()
 
 
+def shard_capacity(capacity: int, n_shards: int, min_cap: int = 64) -> int:
+    """Starting per-shard capacity of a sharded leaf whose device
+    capacity is ``capacity``: 2/n_shards of it, rounded up to a power of
+    two (a balanced hash fits with 2x headroom)."""
+    return _pow2(max(min_cap, min(capacity,
+                                  -(-2 * capacity // max(1, n_shards)))))
+
+
 # ---------------------------------------------------------------------- #
 # shard / gather
 # ---------------------------------------------------------------------- #
@@ -174,8 +193,7 @@ def shard_index(index: CPQxIndex, n_shards: int,
     shard shapes — and the compiled sharded executables keyed on them —
     stable across maintenance flushes."""
     a = index.arrays
-    base = int(a.c2p_v.shape[0])
-    cap0 = _pow2(max(min_cap, min(base, -(-2 * base // max(1, n_shards)))))
+    cap0 = shard_capacity(int(a.c2p_v.shape[0]), n_shards, min_cap)
 
     n_pairs = int(a.pair_count)
     pair_rows = np.stack([
@@ -309,5 +327,5 @@ def gather_index(sharded: ShardedIndexArrays,
         seq_table=sharded.seq_table, seq_count=sharded.seq_count,
         seq_starts=sharded.seq_starts, seq_ends=sharded.seq_ends,
         l2c_cls=sharded.l2c_cls, l2c_count=sharded.l2c_count,
-        overflow=jnp.asarray(False),
+        overflow=jnp.int32(0),
     )

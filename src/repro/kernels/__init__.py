@@ -1,4 +1,3 @@
-"""Pallas TPU kernels for the engine's compute hot spots, each with an
-ops.py jit wrapper (+ jnp fallback) and a ref.py pure-jnp oracle:
-sorted_intersect (CONJUNCTION), expand_join (JOIN / I_c2p materialize),
-fingerprint (signature sets), segment_softmax (GNN substrate)."""
+"""Pallas TPU kernels, each with an ops.py wrapper (+ jnp fallback) and a
+ref.py pure-jnp oracle: fingerprint (signature sets), segment_softmax
+(GNN substrate).  The CPQ query path runs no kernel of this package."""

@@ -1,24 +1,16 @@
-"""Jitted public wrappers for the Pallas kernels, with automatic padding
-and a jnp fallback when the problem exceeds the kernels' VMEM-resident
-assumptions (or when ``REPRO_DISABLE_PALLAS=1``).
+"""Public wrappers for the Pallas kernels, with a jnp fallback when the
+problem exceeds the kernels' VMEM-resident assumptions (or when
+``REPRO_DISABLE_PALLAS=1``).  Tests sweep them against ``ref.py``.
 
-The engine calls these; tests sweep them against ``ref.py``.
+The CPQ query path calls no Pallas kernel: the TPU compiler refuses the
+per-lane vector gathers a sorted-intersection or expansion kernel needs
+("Only 2D gather is supported"), so ``core.backend`` runs those
+operators as XLA programs (``core.relational``).
 
-Two process-wide knobs feed this module from the device cost table
-(``core.costmodel``), both inert by default:
-
-* the **VMEM ceiling** — :func:`vmem_words` derives the broadcast-operand
-  residency budget from the backend (env ``REPRO_VMEM_WORDS`` wins, then
-  a table override installed by :func:`set_vmem_words_override`, then a
-  per-backend default) instead of a hard-coded constant;
-* **tuned block shapes** — :func:`set_tuned_blocks` installs the
-  autotuner's per-capacity-rung ``block_q``/``block_t`` winners
-  (``kernels.autotune``), consulted before the power-of-two heuristic.
-
-They are process-wide (not arguments) because these wrappers are called
-from inside jitted plan walkers where no host-side context can flow; a
-change only affects *future* traces — jit caches compiled with other
-blocks stay valid, just differently tuned.
+The VMEM ceiling (:func:`vmem_words`) is process-wide state fed from the
+device cost table (``core.costmodel.activate``): env
+``REPRO_VMEM_WORDS`` wins, then a table override installed by
+:func:`set_vmem_words_override`, then a per-backend default.
 """
 
 from __future__ import annotations
@@ -26,16 +18,10 @@ from __future__ import annotations
 import os
 
 import jax
-import jax.numpy as jnp
-import numpy as np
 
-from . import expand_join as _ej
 from . import fingerprint as _fp
 from . import ref
 from . import segment_softmax as _ss
-from . import sorted_intersect as _si
-
-SENTINEL = np.int32(2**31 - 1)
 
 # Fallback VMEM-residency ceiling for the broadcast operands (int32
 # words) when neither the env override nor the backend probe decides;
@@ -50,8 +36,6 @@ _TPU_VMEM_WORDS = (8 * 1024 * 1024) // 4
 
 _vmem_override: int | None = None  # set_vmem_words_override (cost table)
 _vmem_probed: int | None = None  # cached backend probe
-_tuned_block_q: dict[int, int] | None = None  # rung -> block
-_tuned_block_t: dict[int, int] | None = None
 
 
 def set_vmem_words_override(words: int | None) -> None:
@@ -83,68 +67,11 @@ def vmem_words() -> int:
     return _vmem_probed
 
 
-def set_tuned_blocks(block_q: dict[int, int] | None,
-                     block_t: dict[int, int] | None) -> None:
-    """Install the autotuner's per-rung block winners ({pow2 rung ->
-    block size}, from ``DeviceCostTable.block_q``/``block_t``); None/None
-    clears back to the power-of-two heuristic."""
-    global _tuned_block_q, _tuned_block_t
-    _tuned_block_q = dict(block_q) if block_q else None
-    _tuned_block_t = dict(block_t) if block_t else None
-
-
-def _tuned(table: dict[int, int] | None, rung: int) -> int | None:
-    """Winner at the smallest tuned rung >= ``rung`` (capacities
-    quantize onto the pow2 ladder, so that neighbor is exact for ladder
-    traffic), else the largest tuned rung's winner."""
-    if not table:
-        return None
-    geq = [r for r in table if r >= rung]
-    return table[min(geq)] if geq else table[max(table)]
-
-
 def _pallas_enabled() -> bool:
     return os.environ.get("REPRO_DISABLE_PALLAS", "0") != "1"
 
 
-def _pad_to(x: jax.Array, n: int, fill) -> jax.Array:
-    if x.shape[0] == n:
-        return x
-    pad = jnp.full((n - x.shape[0],) + x.shape[1:], fill, x.dtype)
-    return jnp.concatenate([x, pad], axis=0)
-
-
-def sorted_member_mask(hay, hay_count, queries, block_q: int = 1024):
-    """0/1 membership of queries in sorted hay[:hay_count]."""
-    if not _pallas_enabled() or hay.shape[0] > vmem_words():
-        return ref.sorted_member_mask(hay, hay_count, queries)
-    n_q = queries.shape[0]
-    rung = max(8, 1 << (n_q - 1).bit_length())
-    tuned = _tuned(_tuned_block_q, rung)
-    blk = min(tuned if tuned is not None else block_q, rung)
-    n_pad = ((n_q + blk - 1) // blk) * blk
-    q = _pad_to(queries, n_pad, SENTINEL)
-    out = _si.sorted_member_mask(hay, hay_count, q, block_q=blk)
-    return out[:n_q]
-
-
-def expand_join_gather(ends, lo, a_payload, b_v, b_u, total, out_capacity,
-                       block_t: int = 1024):
-    if (not _pallas_enabled()
-            or ends.shape[0] + 2 * b_v.shape[0] > vmem_words()):
-        return ref.expand_join_gather(ends, lo, a_payload, b_v, b_u, total,
-                                      out_capacity)
-    rung = max(8, 1 << (out_capacity - 1).bit_length())
-    tuned = _tuned(_tuned_block_t, rung)
-    blk = min(tuned if tuned is not None else block_t, rung)
-    cap = ((out_capacity + blk - 1) // blk) * blk
-    ov, ou, oa = _ej.expand_join_gather(ends, lo, a_payload, b_v, b_u, total,
-                                        cap, block_t=blk)
-    return ov[:out_capacity], ou[:out_capacity], oa[:out_capacity]
-
-
 def fingerprint_rows(cols: tuple, salt: int = 0):
-    n = cols[0].shape[0]
     if not _pallas_enabled():
         return ref.fingerprint_rows(cols, salt)
     return _fp.fingerprint_rows(tuple(cols), salt=salt)

@@ -5,34 +5,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-import numpy as np
-
-SENTINEL = np.int32(2**31 - 1)
-
-
-def sorted_member_mask(hay: jax.Array, hay_count, queries: jax.Array) -> jax.Array:
-    """0/1 membership of queries in hay[:hay_count] (hay sorted)."""
-    pos = jnp.searchsorted(hay, queries, side="left").astype(jnp.int32)
-    posc = jnp.clip(pos, 0, hay.shape[0] - 1)
-    found = (pos < hay_count) & (hay[posc] == queries)
-    return found.astype(jnp.int32)
-
-
-def expand_join_gather(ends, lo, a_payload, b_v, b_u, total, out_capacity,
-                       sentinel: int = int(SENTINEL)):
-    n_a = ends.shape[0]
-    n_b = b_v.shape[0]
-    t = jnp.arange(out_capacity, dtype=jnp.int32)
-    ai = jnp.searchsorted(ends, t, side="right").astype(jnp.int32)
-    aic = jnp.clip(ai, 0, n_a - 1)
-    starts = jnp.where(aic > 0, ends[jnp.clip(aic - 1, 0, n_a - 1)], 0)
-    bj = jnp.clip(lo[aic] + (t - starts), 0, n_b - 1)
-    ok = t < total
-    return (
-        jnp.where(ok, b_v[bj], sentinel),
-        jnp.where(ok, b_u[bj], sentinel),
-        jnp.where(ok, a_payload[aic], sentinel),
-    )
 
 
 def fingerprint_rows(cols: tuple, salt: int = 0):

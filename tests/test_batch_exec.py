@@ -107,6 +107,28 @@ class TestBatchOverflowRetry:
         for q, r in zip(qs, res):
             assert _rows(r) == oracle.cpq_eval(g, q), q
 
+    @pytest.mark.parametrize("batched", [False, True])
+    def test_only_the_overflowed_capacity_grows(self, batched):
+        """A join-free lookup whose pair capacity is too small: the
+        ladder doubles the pair capacity alone, so a generous join
+        capacity never grows with it (it would outgrow HBM first)."""
+        g = _heavy_graph()
+        eng = Engine(cindex.build(g, 2))
+        seen = []
+        run, run_batch = eng.backend.run, eng.backend.run_batch
+        eng.backend.run = lambda s, c, r: seen.append(c) or run(s, c, r)
+        eng.backend.run_batch = (
+            lambda s, c, r: seen.append(c) or run_batch(s, c, r))
+        q = instantiate_template("C2", [0, 0])  # 72 answer pairs
+        start = QueryCaps(64, 4, 1 << 10)
+        rows = (eng.execute_batch([q], caps=start)[0] if batched
+                else eng.execute(q, caps=start))
+        assert _rows(rows) == oracle.cpq_eval(g, q)
+        assert len(seen) > 1  # the ladder climbed
+        assert {c.join_cap for c in seen} == {1 << 10}
+        assert {c.class_cap for c in seen} == {64}
+        assert seen[-1].pair_cap >= 72
+
     def test_min_bucket_variants_agree(self, built):
         """Bucket merging is a perf knob, never a semantics knob."""
         g, eng = built

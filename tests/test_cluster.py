@@ -107,6 +107,24 @@ class TestThreadFabric:
                     idx, 3, shape, caps.doubled().doubled(), ranges[None])
                 assert not ovf[0] and np.array_equal(results[0], expect), q
 
+    def test_overflow_word_names_the_short_capacity(self):
+        """The merged per-worker overflow word carries the bit of the
+        capacity that was short (pair), not every capacity's."""
+        from repro.core import index as cindex
+        from repro.core.backend import OVF_PAIR, QueryCaps
+        from test_batch_exec import _heavy_graph
+
+        g = _heavy_graph()
+        idx = cindex.build(g, 2)
+        eng = Engine(idx)
+        plan = eng.plan(instantiate_template("C2", [0, 0]))
+        ranges = eng.lookup_ranges(plan)
+        results, ovf = _thread_cluster_run(
+            idx, 2, plan_shape(plan), QueryCaps(64, 4, 1 << 10),
+            ranges[None])
+        assert results[0] is None
+        assert int(ovf[0]) == OVF_PAIR
+
     def test_exchange_tags_drop_stale_rounds(self):
         fabrics, _abort = cl.make_thread_fabrics(2)
         a, b = fabrics
@@ -305,3 +323,18 @@ class TestReshard:
         with pytest.raises(ValueError):
             fleet["engine"].backend.resize(
                 fleet["engine"].backend.runtime.max_workers + 1)
+
+
+class TestOneProcessPerChip:
+    def test_accelerator_host_refuses_before_spawning(self, monkeypatch):
+        """A chip belongs to one process: on an accelerator backend the
+        runtime fails at once, before any worker or queue exists."""
+        monkeypatch.setattr(cl.jax, "default_backend", lambda: "tpu")
+        spawned = []
+        monkeypatch.setattr(cl.ClusterRuntime, "_spawn",
+                            lambda self, rank: spawned.append(rank))
+        t0 = time.perf_counter()
+        with pytest.raises(cl.ClusterError, match="in one process"):
+            cl.ClusterRuntime(n_workers=2)
+        assert spawned == []
+        assert time.perf_counter() - t0 < 5.0

@@ -38,8 +38,6 @@ def _toy_table(**overrides) -> DeviceCostTable:
              "join": OpCost(5_000.0, 3.0),
              "identity": OpCost(50.0, 0.5),
              "union_step": OpCost(80.0, 0.0)},
-        block_q={256: 64, 4096: 512},
-        block_t={1024: 128},
         vmem_words=123_456,
         samples={"join": [[256.0, 5768.0], [1024.0, 8072.0]]},
     )
@@ -95,7 +93,6 @@ class TestTableCodec:
         back = DeviceCostTable.from_json(t.to_json())
         assert back.to_json() == t.to_json()
         assert back.ops["join"] == t.ops["join"]
-        assert back.block_q == t.block_q and back.block_t == t.block_t
         assert back.vmem_words == t.vmem_words
 
     def test_save_load_round_trip(self, tmp_path):
@@ -152,14 +149,6 @@ class TestPricing:
         risky = t.expected_dispatch_ns(1024, est_rows=1000, risky=True)
         assert base <= safe < risky
         assert risky <= base + t.plan_dispatch_ns(2048)
-
-    def test_tuned_block_right_neighbor(self):
-        t = _toy_table()
-        assert t.tuned_block("block_q", 256) == 64
-        assert t.tuned_block("block_q", 300) == 512  # next rung up
-        assert t.tuned_block("block_q", 1 << 20) == 512  # largest known
-        assert t.tuned_block("block_t", 8) == 128
-        assert DeviceCostTable().tuned_block("block_q", 256) is None
 
 
 # ---------------------------------------------------------------------- #

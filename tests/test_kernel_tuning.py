@@ -1,20 +1,15 @@
-"""The kernel wrappers' tuning surface (PR 8): the derived VMEM ceiling
-with its Pallas -> XLA fallback boundary, and the autotuned per-rung
-block-shape registry.  Separate from test_kernels.py so these run
-without hypothesis."""
+"""The kernel wrappers' VMEM ceiling: derived (env > cost-table
+override > backend default), with a Pallas -> XLA fallback boundary that
+must be invisible in the answers.  Separate from test_kernels.py so
+these run without hypothesis."""
 
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import ops
+from repro.kernels import ops, ref
 
 
 class TestVmemBoundary:
-    """The Pallas -> XLA fallback at the VMEM ceiling must be invisible:
-    bit-identical answers on either side of the boundary, whichever path
-    runs.  The ceiling itself is derived (env > table override > backend
-    default), no longer a hard-coded constant."""
-
     def test_vmem_words_resolution_order(self, monkeypatch):
         monkeypatch.delenv("REPRO_VMEM_WORDS", raising=False)
         ops.set_vmem_words_override(None)
@@ -28,75 +23,19 @@ class TestVmemBoundary:
         ops.set_vmem_words_override(None)
         assert ops.vmem_words() == base
 
-    def test_member_mask_bit_identical_at_exact_ceiling(self, monkeypatch):
-        n_hay = 256
-        rng = np.random.default_rng(7)
-        hay = np.sort(rng.choice(5 * n_hay, n_hay,
-                                 replace=False)).astype(np.int32)
-        q = rng.integers(0, 5 * n_hay, 300).astype(np.int32)
-        args = (jnp.array(hay), n_hay, jnp.array(q))
-        # hay.shape[0] == ceiling: kernel path (the guard is strict >)
-        monkeypatch.setenv("REPRO_VMEM_WORDS", str(n_hay))
-        at = np.asarray(ops.sorted_member_mask(*args))
-        # one word less: fallback path
-        monkeypatch.setenv("REPRO_VMEM_WORDS", str(n_hay - 1))
-        below = np.asarray(ops.sorted_member_mask(*args))
-        np.testing.assert_array_equal(at, below)
-        np.testing.assert_array_equal(
-            at, np.isin(q, hay[:n_hay]).astype(np.int32))
-
-    def test_expand_join_bit_identical_at_exact_ceiling(self, monkeypatch):
-        rng = np.random.default_rng(11)
-        n_a, n_b = 32, 48
-        a = rng.integers(0, 6, (n_a, 2)).astype(np.int32)
-        b = rng.integers(0, 6, (n_b, 2)).astype(np.int32)
-        b = b[np.lexsort((b[:, 1], b[:, 0]))]
-        lo = np.searchsorted(b[:, 0], a[:, 1], "left").astype(np.int32)
-        hi = np.searchsorted(b[:, 0], a[:, 1], "right").astype(np.int32)
-        ends = np.cumsum(hi - lo).astype(np.int32)
-        total = int(ends[-1])
-        cap = max(8, 1 << max(0, (total - 1)).bit_length())
-        args = (jnp.array(ends), jnp.array(lo), jnp.array(a[:, 0]),
-                jnp.array(b[:, 0]), jnp.array(b[:, 1]), total, cap)
-        words = n_a + 2 * n_b  # the wrapper's residency formula
+    def test_segment_softmax_bit_identical_at_exact_ceiling(self,
+                                                            monkeypatch):
+        rng = np.random.default_rng(5)
+        e, d, n = 512, 4, 64
+        scores = jnp.asarray(rng.normal(0, 2, (e, d)).astype(np.float32))
+        seg = jnp.asarray(np.sort(rng.integers(0, n, e)).astype(np.int32))
+        words = n * d  # the wrapper's residency formula
+        # at the ceiling: kernel path (the guard is strict >)
         monkeypatch.setenv("REPRO_VMEM_WORDS", str(words))
-        at = [np.asarray(x) for x in ops.expand_join_gather(*args)]
+        at = np.asarray(ops.segment_softmax(scores, seg, n))
+        # one word less: the XLA reference
         monkeypatch.setenv("REPRO_VMEM_WORDS", str(words - 1))
-        below = [np.asarray(x) for x in ops.expand_join_gather(*args)]
-        for g, e in zip(at, below):
-            np.testing.assert_array_equal(g, e)
-
-
-class TestTunedBlocks:
-    def test_tuned_block_q_changes_nothing_but_speed(self):
-        """Installing autotuned winners must keep answers bit-identical
-        (the sweep's own invariant, re-checked through the wrapper)."""
-        rng = np.random.default_rng(3)
-        n = 1024
-        hay = np.sort(rng.choice(8 * n, n, replace=False)).astype(np.int32)
-        q = rng.integers(0, 8 * n, n).astype(np.int32)
-        args = (jnp.array(hay), n, jnp.array(q))
-        base = np.asarray(ops.sorted_member_mask(*args))
-        try:
-            ops.set_tuned_blocks({1024: 256}, {1024: 512})
-            tuned = np.asarray(ops.sorted_member_mask(*args))
-        finally:
-            ops.set_tuned_blocks(None, None)
-        np.testing.assert_array_equal(base, tuned)
-
-    def test_tuned_rung_lookup_picks_right_neighbor(self):
-        ops.set_tuned_blocks({256: 64, 4096: 1024}, None)
-        try:
-            assert ops._tuned(ops._tuned_block_q, 256) == 64
-            assert ops._tuned(ops._tuned_block_q, 512) == 1024  # next up
-            assert ops._tuned(ops._tuned_block_q, 1 << 20) == 1024  # largest
-        finally:
-            ops.set_tuned_blocks(None, None)
-
-    def test_autotune_winners_fit_their_rung(self):
-        from repro.kernels.autotune import autotune
-
-        block_q, block_t, raw = autotune([256], repeats=1)
-        assert set(block_q) == set(block_t) == {256}
-        assert block_q[256] <= 256 and block_t[256] <= 256
-        assert raw  # timings emitted for the bench trajectory
+        below = np.asarray(ops.segment_softmax(scores, seg, n))
+        np.testing.assert_allclose(at, below, rtol=1e-6, atol=1e-6)
+        np.testing.assert_array_equal(
+            below, np.asarray(ref.segment_softmax(scores, seg, n)))

@@ -1,5 +1,7 @@
 """Per-kernel validation: Pallas (interpret mode on CPU) vs the pure-jnp
-oracle in ref.py, swept across shapes and dtypes."""
+oracle in ref.py, swept across shapes and dtypes — and the XLA programs
+that run the query path's conjunction and expansion (``core.relational``)
+against numpy ground truth."""
 
 import jax
 import jax.numpy as jnp
@@ -8,12 +10,21 @@ import pytest
 pytest.importorskip("hypothesis")  # property tests need hypothesis
 from hypothesis import given, settings, strategies as st
 
+from repro.core import relational as R
 from repro.kernels import ops, ref
 
 SENTINEL = np.int32(2**31 - 1)
 
 
 class TestSortedIntersect:
+    """Class-id membership (Prop. 4.1) as ``conj_classes`` runs it: one
+    binary search per probe into the sorted list's valid prefix."""
+
+    @staticmethod
+    def _member(hay, count, queries):
+        return (R.lex_count_matches((hay,), (queries,), count) > 0
+                ).astype(np.int32)
+
     @pytest.mark.parametrize("n_hay", [1, 7, 128, 1000])
     @pytest.mark.parametrize("n_q", [1, 64, 1024, 1500])
     def test_shape_sweep(self, n_hay, n_q):
@@ -21,21 +32,22 @@ class TestSortedIntersect:
         hay = np.sort(rng.choice(5 * n_hay, n_hay, replace=False)).astype(np.int32)
         count = rng.integers(0, n_hay + 1)
         queries = rng.integers(0, 5 * n_hay, n_q).astype(np.int32)
-        got = np.asarray(ops.sorted_member_mask(jnp.array(hay), count, jnp.array(queries)))
-        exp = np.asarray(ref.sorted_member_mask(jnp.array(hay), count, jnp.array(queries)))
-        np.testing.assert_array_equal(got, exp)
-        # and vs python ground truth
+        got = np.asarray(self._member(jnp.array(hay), count,
+                                      jnp.array(queries)))
         gt = np.isin(queries, hay[:count]).astype(np.int32)
         np.testing.assert_array_equal(got, gt)
 
     def test_sentinel_queries_never_match(self):
         hay = jnp.array([1, 5, 9, SENTINEL], jnp.int32)
         q = jnp.array([5, SENTINEL, 9, SENTINEL], jnp.int32)
-        got = np.asarray(ops.sorted_member_mask(hay, 3, q))
+        got = np.asarray(self._member(hay, 3, q))
         np.testing.assert_array_equal(got, [1, 0, 1, 0])
 
 
 class TestExpandJoin:
+    """The expansion the join and ``materialize`` run
+    (``relational.expansion_join`` over ``expand_ranges``)."""
+
     @given(seed=st.integers(0, 2**31 - 1))
     @settings(max_examples=20, deadline=None)
     def test_matches_ref_and_python(self, seed):
@@ -44,27 +56,24 @@ class TestExpandJoin:
         a = rng.integers(0, 6, (n_a, 2)).astype(np.int32)
         b = rng.integers(0, 6, (n_b, 2)).astype(np.int32)
         b = b[np.lexsort((b[:, 1], b[:, 0]))]
-        lo = np.searchsorted(b[:, 0], a[:, 1], "left").astype(np.int32)
-        hi = np.searchsorted(b[:, 0], a[:, 1], "right").astype(np.int32)
-        cnt = hi - lo
-        ends = np.cumsum(cnt).astype(np.int32)
-        total = int(ends[-1]) if n_a else 0
-        cap = max(8, 1 << max(0, (total - 1)).bit_length())
-        args = (jnp.array(ends), jnp.array(lo), jnp.array(a[:, 0]),
-                jnp.array(b[:, 0]), jnp.array(b[:, 1]), total, cap)
-        got = [np.asarray(x) for x in ops.expand_join_gather(*args)]
-        exp = [np.asarray(x) for x in ref.expand_join_gather(*args)]
-        for g, e in zip(got, exp):
-            np.testing.assert_array_equal(g, e)
-        # python ground truth of the join projection
         rows = sorted(
             (int(bv), int(bu), int(av))
             for (av, ak) in a
             for (bv, bu) in b
             if bv == ak
         )
-        got_rows = sorted(zip(*(g[:total].tolist() for g in got)))
+        cap = max(8, 1 << max(0, (len(rows) - 1)).bit_length())
+        out = R.expansion_join(R.from_numpy(a, n_a), R.from_numpy(b, n_b),
+                               a_on=[1],
+                               out_cols=[("b", 0), ("b", 1), ("a", 0)],
+                               out_capacity=cap)
+        assert not bool(out.overflow)
+        assert int(out.count) == len(rows)
+        got = [np.asarray(c) for c in out.cols]
+        got_rows = sorted(zip(*(g[:len(rows)].tolist() for g in got)))
         assert got_rows == rows
+        for g in got:  # padding past the output count
+            assert np.all(g[len(rows):] == SENTINEL)
 
 
 class TestFingerprint:

@@ -1,7 +1,7 @@
 """ShardedBackend parity — in-process, over every visible device.
 
 The whole-plan sharded executor (replicated class space, canonical
-hash-partitioned pair space, psum'd overflow) runs fine on a mesh of one
+hash-partitioned pair space, OR-reduced overflow words) runs fine on a mesh of one
 device — every exchange is a self-send — so the full equivalence matrix
 ``ShardedBackend == LocalBackend == numpy oracle`` is checked here
 without subprocess machinery.  The mesh spans ``jax.device_count()``
@@ -63,6 +63,25 @@ class TestShardedEngineParity:
             assert a.shape == b.shape and np.array_equal(a, b), name
             assert _rows_set(b) == oracle.cpq_eval(ex_graph, q), name
 
+    def test_index_leaves_placed_on_the_mesh(self, ex_graph, mesh1):
+        """Every leaf carries the NamedSharding of its spec, before the
+        first query and after a rebind reshards it."""
+        from jax.sharding import NamedSharding
+
+        from repro.core.sharded_index import index_specs
+
+        idx = cindex.build(ex_graph, 2)
+        eng = Engine(idx, mesh=mesh1)
+        n = jax.device_count()
+        for _ in range(2):
+            for leaf, spec in zip(eng.backend.sharded, index_specs("engine")):
+                assert isinstance(leaf.sharding, NamedSharding)
+                assert leaf.sharding.spec == spec
+                assert len(leaf.sharding.device_set) == n
+                if spec != jax.sharding.PartitionSpec():
+                    assert leaf.sharding.shard_shape(leaf.shape)[0] == 1
+            eng.rebind(idx)
+
     def test_identity_and_parse_paths(self, ex_graph, mesh1):
         idx = cindex.build(ex_graph, 2)
         local, sharded = Engine(idx), Engine(idx, mesh=mesh1)
@@ -86,14 +105,36 @@ class TestShardedEngineParity:
             assert np.array_equal(rows, sharded.execute(q))
 
     def test_overflow_ladder_retries_to_exact(self, ex_graph, mesh1):
-        """Deliberately tiny caps: the psum'd sticky flag must drive the
-        host double-and-retry to the exact answer, same as local."""
+        """Deliberately tiny caps: the reduced sticky overflow word must
+        drive the host double-and-retry to the exact answer, same as
+        local."""
         idx = cindex.build(ex_graph, 2)
         sharded = Engine(idx, mesh=mesh1)
         q = parse("l0 . l1", None, ex_graph.n_labels)
         tiny = QueryCaps(class_cap=2, pair_cap=2, join_cap=2)
         rows = sharded.execute(q, caps=tiny)
         assert _rows_set(rows) == oracle.cpq_eval(ex_graph, q)
+
+    @pytest.mark.parametrize("batched", [False, True])
+    def test_only_the_overflowed_capacity_grows(self, mesh1, batched):
+        """The shards' overflow words name the short capacity: a lookup
+        whose pair capacity is too small climbs the pair rung alone."""
+        from test_batch_exec import _heavy_graph
+
+        g = _heavy_graph()
+        eng = Engine(cindex.build(g, 2), mesh=mesh1)
+        seen = []
+        run = eng.backend.run
+        eng.backend.run = lambda s, c, r: seen.append(c) or run(s, c, r)
+        q = instantiate_template("C2", [0, 0])  # 72 answer pairs
+        start = QueryCaps(64, 4, 1 << 10)
+        rows = (eng.execute_batch([q], caps=start)[0] if batched
+                else eng.execute(q, caps=start))
+        assert _rows_set(rows) == oracle.cpq_eval(g, q)
+        assert len(seen) > 1  # the ladder climbed
+        assert {c.join_cap for c in seen} == {1 << 10}
+        assert {c.class_cap for c in seen} == {64}
+        assert seen[-1].pair_cap >= 72
 
 
 class TestShardedService:
