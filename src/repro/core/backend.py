@@ -85,6 +85,7 @@ import numpy as np
 from . import relational as R
 from .index import DeviceIndexArrays
 from .paths import _recap
+from .tracing import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -120,10 +121,12 @@ def default_caps(index) -> QueryCaps:
 def _join_pairs(a: R.Relation, b: R.Relation, join_cap: int, pair_cap: int) -> R.Relation:
     """(v,u) ⋈ (x,y) on u == x -> distinct (v, y).  b sorted by (x, y).
     The expansion overflows ``join_cap``, the deduped result ``pair_cap``."""
-    out = R.expansion_join(a, b, a_on=[1], out_cols=[("a", 0), ("b", 1)],
-                           out_capacity=join_cap, bit=OVF_JOIN)
-    out = R.rel_unique(R.rel_sort(out, num_keys=2), 2)
-    return _recap(out, pair_cap, OVF_PAIR)
+    with jax.named_scope("join"):
+        out = R.expansion_join(a, b, a_on=[1], out_cols=[("a", 0), ("b", 1)],
+                               out_capacity=join_cap, bit=OVF_JOIN)
+        out = R.rel_unique(R.rel_sort(out, num_keys=2), 2)
+    with jax.named_scope("recap"):
+        return _recap(out, pair_cap, OVF_PAIR)
 
 
 # ---------------------------------------------------------------------- #
@@ -160,12 +163,13 @@ class PlanOps:
     # ---- class space ---- #
 
     def lookup_classes(self, start, length, cap: int) -> R.Relation:
-        idx = jnp.arange(cap, dtype=R.I32)
-        valid = idx < length
-        src = jnp.clip(start + idx, 0, self.l2c_cls.shape[0] - 1)
-        ids = jnp.where(valid, self.l2c_cls[src], R.SENTINEL)
-        return R.Relation((ids,), jnp.minimum(length, cap).astype(R.I32),
-                          R.overflow_bit(length > cap, OVF_CLASS))
+        with jax.named_scope("lookup"):
+            idx = jnp.arange(cap, dtype=R.I32)
+            valid = idx < length
+            src = jnp.clip(start + idx, 0, self.l2c_cls.shape[0] - 1)
+            ids = jnp.where(valid, self.l2c_cls[src], R.SENTINEL)
+            return R.Relation((ids,), jnp.minimum(length, cap).astype(R.I32),
+                              R.overflow_bit(length > cap, OVF_CLASS))
 
     def conj_classes(self, a: R.Relation, b: R.Relation) -> R.Relation:
         """Prop. 4.1 on device: ``a``'s class ids found in the sorted list
@@ -175,10 +179,11 @@ class PlanOps:
             return R.rel_intersect(a, b, 1)
 
     def conj_id_classes(self, classes: R.Relation) -> R.Relation:
-        cyc = self.class_cyclic[
-            jnp.clip(classes.cols[0], 0, self.class_cyclic.shape[0] - 1)]
-        keep = (cyc == 1) & R.valid_mask(classes)
-        return R.rel_compact(classes, keep)
+        with jax.named_scope("conj_id"):
+            cyc = self.class_cyclic[
+                jnp.clip(classes.cols[0], 0, self.class_cyclic.shape[0] - 1)]
+            keep = (cyc == 1) & R.valid_mask(classes)
+            return R.rel_compact(classes, keep)
 
     # ---- pair space ---- #
 
@@ -192,9 +197,10 @@ class PlanOps:
             cnt = self.class_starts[cid + 1] - lo
             cnt = jnp.where(R.valid_mask(classes), cnt, 0).astype(R.I32)
             _, bj, valid, total = R.expand_ranges(lo, cnt, pair_cap)
-            bj = jnp.clip(bj, 0, self.c2p_v.shape[0] - 1)
-            v = jnp.where(valid, self.c2p_v[bj], R.SENTINEL)
-            u = jnp.where(valid, self.c2p_u[bj], R.SENTINEL)
+            with jax.named_scope("expand"):
+                bj = jnp.clip(bj, 0, self.c2p_v.shape[0] - 1)
+                v = jnp.where(valid, self.c2p_v[bj], R.SENTINEL)
+                u = jnp.where(valid, self.c2p_u[bj], R.SENTINEL)
             rel = R.Relation(
                 (v, u), jnp.minimum(total, pair_cap).astype(R.I32),
                 classes.overflow | R.overflow_bit(total > pair_cap, OVF_PAIR))
@@ -205,19 +211,22 @@ class PlanOps:
         return _join_pairs(a, b, join_cap, pair_cap)
 
     def conj_pairs(self, a: R.Relation, b: R.Relation) -> R.Relation:
-        return R.rel_intersect(a, b, 2)
+        with jax.named_scope("conj_pairs"):
+            return R.rel_intersect(a, b, 2)
 
     def conj_id_pairs(self, pairs: R.Relation) -> R.Relation:
-        return R.rel_compact(pairs, pairs.cols[0] == pairs.cols[1])
+        with jax.named_scope("conj_id"):
+            return R.rel_compact(pairs, pairs.cols[0] == pairs.cols[1])
 
     def identity_pairs(self, pair_cap: int) -> R.Relation:
-        v = jnp.arange(pair_cap, dtype=R.I32)
-        m = v < self.n_vertices
-        col = jnp.where(m, v, R.SENTINEL)
-        return R.Relation(
-            (col, col),
-            jnp.asarray(min(self.n_vertices, pair_cap), R.I32),
-            R.overflow_bit(self.n_vertices > pair_cap, OVF_PAIR))
+        with jax.named_scope("identity"):
+            v = jnp.arange(pair_cap, dtype=R.I32)
+            m = v < self.n_vertices
+            col = jnp.where(m, v, R.SENTINEL)
+            return R.Relation(
+                (col, col),
+                jnp.asarray(min(self.n_vertices, pair_cap), R.I32),
+                R.overflow_bit(self.n_vertices > pair_cap, OVF_PAIR))
 
     # ---- epilogue ---- #
 
@@ -517,6 +526,10 @@ class ExecutionBackend(abc.ABC):
     #: back to per-shape dispatches when it is not).
     supports_union = False
 
+    #: bytes this backend has copied from the device to the host for
+    #: answers, cumulative (a backend that copies none reports 0).
+    d2h_bytes = 0
+
     @abc.abstractmethod
     def run(self, shape, caps: QueryCaps, ranges: np.ndarray):
         """One query.  ``ranges`` (n_lookups, 2) -> (rows | None, overflow):
@@ -580,8 +593,11 @@ class LocalBackend(ExecutionBackend):
     def run(self, shape, caps: QueryCaps, ranges: np.ndarray):
         pairs, overflow = run_plan(self.arrays, shape, caps, self.n_vertices,
                                    jnp.asarray(ranges))
+        self.d2h_bytes += overflow.nbytes
         if int(overflow):
             return None, int(overflow)
+        self.d2h_bytes += pairs.count.nbytes + sum(c.nbytes
+                                                   for c in pairs.cols)
         return R.to_numpy(pairs), 0
 
     def run_batch(self, shape, caps: QueryCaps, ranges: np.ndarray):
@@ -615,10 +631,16 @@ class LocalBackend(ExecutionBackend):
         if handle[0] != "lanes":
             return super().harvest_batch(handle)
         _, rel, overflow = handle
-        overflow = np.asarray(overflow)
+        with span("harvest.wait"):
+            overflow = np.asarray(overflow)
+        self.d2h_bytes += overflow.nbytes
         results: list = [None] * overflow.shape[0]
         ok = np.nonzero(overflow == 0)[0]
         if ok.size:
-            for lane, rows in zip(ok, R.batch_to_numpy(rel, lanes=ok)):
+            with span("harvest.copy"):
+                lanes = R.batch_to_numpy(rel, lanes=ok)
+            self.d2h_bytes += rel.count.nbytes + sum(c.nbytes
+                                                     for c in rel.cols)
+            for lane, rows in zip(ok, lanes):
                 results[lane] = rows
         return results, overflow

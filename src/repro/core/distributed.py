@@ -477,8 +477,10 @@ class ShardedBackend(B.ExecutionBackend):
         with compat.set_mesh(self.mesh):
             cols, counts, ovf = fn(self.sharded, jnp.asarray(ranges))
         kinds = int(np.bitwise_or.reduce(np.asarray(ovf).ravel()))
+        self.d2h_bytes += ovf.nbytes
         if kinds:
             return None, kinds
+        self.d2h_bytes += counts.nbytes + cols[0].nbytes + cols[1].nbytes
         return self._gather_rows(cols, counts), 0
 
     def compile_batch(self, shape, caps: B.QueryCaps, ranges: np.ndarray):

@@ -29,6 +29,7 @@ import dataclasses
 import numpy as np
 
 from .backend import (  # noqa: F401  (QueryCaps/run_plan* are public API)
+    OP_JOIN,
     OP_NOP,
     OVF_CLASS,
     OVF_JOIN,
@@ -47,6 +48,7 @@ from .index import CPQxIndex
 from .optimizer import estimate_plan, optimize_query
 from .query import CPQ, plan_query, plan_lookup_seqs, plan_shape
 from .stats import IndexStats
+from .tracing import span
 
 
 # ---------------------------------------------------------------------- #
@@ -65,37 +67,71 @@ def _has_identity(shape) -> bool:
                if isinstance(s, tuple))
 
 
+def _joins_pairs(shape) -> bool:
+    """Whether a plan shape joins in pair space: a JOIN node, or a LOOKUP
+    of several segments (joined one after another)."""
+    if shape[0] == "lookup":
+        nseg = shape[1] if isinstance(shape[1], int) else len(shape[1])
+        return nseg > 1
+    return shape[0] == "join" or any(
+        _joins_pairs(s) for s in shape[1:] if isinstance(s, tuple))
+
+
+def _cap_rows(shape, opcodes, caps: QueryCaps, lanes: int) -> int:
+    """Rows one dispatch of ``lanes`` lanes is sized for: ``join_cap`` for
+    each lane whose plan joins in pair space (the expansion's output is
+    its largest relation), ``pair_cap`` for each other lane.  A union
+    group (``opcodes`` set) decides lane by lane."""
+    if opcodes is not None:
+        joins = int((opcodes == OP_JOIN).any(axis=1).sum())
+    else:
+        joins = lanes if _joins_pairs(shape) else 0
+    return joins * caps.join_cap + (lanes - joins) * caps.pair_cap
+
+
 @dataclasses.dataclass
 class LadderTelemetry:
-    """Cumulative capacity-ladder counters of one engine (reset on
-    demand, *not* on rebind — they track the engine's lifetime traffic).
+    """The engine's cumulative counters: the capacity ladder, the rows the
+    walker is sized for against the rows it answers, and the bytes
+    harvest copies to the host (reset on demand, *not* on rebind — they
+    track the engine's lifetime traffic).
 
     Wall-clock hides retries: a query that ladders three rungs before
     fitting looks like one slow query.  These counters make estimator
     regressions (and estimator wins, e.g. from richer statistics or an
-    adapted interest set) directly visible — ``ServiceStats`` and the
-    bench JSON surface them.
+    adapted interest set) directly visible, and the ratios of the last
+    three say how far capacities and copies exceed the answers.
 
     ``queries``      — queries evaluated (batch lanes count individually);
     ``dispatches``   — device dispatches, including every retry rung;
     ``retry_rungs``  — ladder rungs climbed past the first attempt,
                        summed per query/lane (0 when the estimate fit);
     ``default_jumps``— escalations that hit the jump-to-default rung
-                       (attempt >= 3 — the expensive worst-case dispatch).
+                       (attempt >= 3 — the expensive worst-case dispatch);
+    ``union_lanes``  — lanes served through the union executable;
+    ``cap_rows``     — rows the walker was sized for, over every dispatch
+                       including retry rungs: per lane ``join_cap`` where
+                       the plan joins in pair space, else ``pair_cap``;
+    ``answer_pairs`` — answer rows returned to callers;
+    ``d2h_bytes``    — bytes harvest copied from the device to the host,
+                       as the backend that copied them reports.
     """
 
     queries: int = 0
     dispatches: int = 0
     retry_rungs: int = 0
     default_jumps: int = 0
-    union_lanes: int = 0  # lanes served through the union executable
+    union_lanes: int = 0
+    cap_rows: int = 0
+    answer_pairs: int = 0
+    d2h_bytes: int = 0
 
     def snapshot(self) -> "LadderTelemetry":
         return dataclasses.replace(self)
 
     def reset(self) -> None:
-        self.queries = self.dispatches = 0
-        self.retry_rungs = self.default_jumps = self.union_lanes = 0
+        for f in dataclasses.fields(self):
+            setattr(self, f.name, 0)
 
 
 @dataclasses.dataclass
@@ -222,11 +258,12 @@ class Engine:
         """Compile ``q`` to a physical plan: cost-optimized against the
         index statistics by default, syntactic (``plan_query``) when the
         engine was constructed with ``optimize=False``."""
-        if self.optimize:
-            return optimize_query(q, self.index.k, self.stats,
-                                  available=self._available,
-                                  cost_table=self.cost_table)
-        return plan_query(q, self.index.k, available=self._available)
+        with span("engine.plan"):
+            if self.optimize:
+                return optimize_query(q, self.index.k, self.stats,
+                                      available=self._available,
+                                      cost_table=self.cost_table)
+            return plan_query(q, self.index.k, available=self._available)
 
     def predict_cost_ns(self, plan) -> float:
         """Calibrated prediction of one dispatch of ``plan`` in device
@@ -317,8 +354,12 @@ class Engine:
         self.telemetry.queries += 1
         for attempt in range(max_retries):
             self.telemetry.dispatches += 1
+            self.telemetry.cap_rows += _cap_rows(shape, None, caps, 1)
+            copied = self.backend.d2h_bytes
             rows, overflow = self.backend.run(shape, caps, ranges)
+            self.telemetry.d2h_bytes += self.backend.d2h_bytes - copied
             if not overflow:
+                self.telemetry.answer_pairs += len(rows)
                 return rows
             self.telemetry.retry_rungs += 1
             caps = self._escalate(caps, attempt, int(overflow))
@@ -406,12 +447,15 @@ class Engine:
         skip re-planning; must align with ``queries``."""
         if not queries:
             return BatchHandle(results=[], groups=[])
-        groups = self.group_batch(queries, caps=caps, plans=plans,
-                                  min_bucket=min_bucket, union=union)
-        self.telemetry.queries += len(queries)
-        for g in groups:
-            self.telemetry.dispatches += 1
-            g.handle = self._dispatch_group(g)
+        with span("engine.dispatch"):
+            groups = self.group_batch(queries, caps=caps, plans=plans,
+                                      min_bucket=min_bucket, union=union)
+            self.telemetry.queries += len(queries)
+            for g in groups:
+                self.telemetry.dispatches += 1
+                self.telemetry.cap_rows += _cap_rows(
+                    g.shape, g.opcodes, g.caps, len(g.members))
+                g.handle = self._dispatch_group(g)
         return BatchHandle(results=[None] * len(queries), groups=groups)
 
     def group_batch(self, queries, caps: QueryCaps | None = None,
@@ -421,6 +465,11 @@ class Engine:
         ``queries`` (same arguments), planned and bucketed but not
         dispatched — each is one executable, so a caller can compile
         them ahead of traffic."""
+        with span("engine.group_batch"):
+            return self._group_batch(queries, caps, plans, min_bucket,
+                                     union)
+
+    def _group_batch(self, queries, caps, plans, min_bucket, union) -> list:
         if plans is None:
             plans = [self.plan(q) for q in queries]
         all_ranges = [self.lookup_ranges(p) for p in plans]
@@ -512,6 +561,14 @@ class Engine:
         ``retry_rungs`` and ``default_jumps`` both count per lane — a
         4-lane bucket that jumps to default caps records 4 jumps, the
         same as 4 single-query executes would."""
+        with span("engine.harvest"):
+            copied = self.backend.d2h_bytes
+            try:
+                return self._harvest_batch(handle, max_retries)
+            finally:
+                self.telemetry.d2h_bytes += self.backend.d2h_bytes - copied
+
+    def _harvest_batch(self, handle: BatchHandle, max_retries: int) -> list:
         results = handle.results
         for g in handle.groups:
             if max_retries <= 0:
@@ -526,6 +583,7 @@ class Engine:
                 for lane, r in enumerate(rows):
                     if r is not None:
                         results[pending[lane]] = r
+                        self.telemetry.answer_pairs += len(r)
                 overflow = np.asarray(overflow)
                 tripped = overflow != 0
                 if not tripped.any():
@@ -544,12 +602,16 @@ class Engine:
                         "query overflow not resolved after retries")
                 pending = pending[tripped]
                 ranges = ranges[tripped]
-                self.telemetry.dispatches += 1
                 if opcodes is not None:
                     opcodes = opcodes[tripped]
-                    rows, overflow = self.backend.run_union_batch(
-                        opcodes, grp_caps, g.stack_size, ranges)
-                else:
-                    rows, overflow = self.backend.run_batch(
-                        g.shape, grp_caps, ranges)
+                self.telemetry.dispatches += 1
+                self.telemetry.cap_rows += _cap_rows(
+                    g.shape, opcodes, grp_caps, len(pending))
+                with span("harvest.retry"):
+                    if opcodes is not None:
+                        rows, overflow = self.backend.run_union_batch(
+                            opcodes, grp_caps, g.stack_size, ranges)
+                    else:
+                        rows, overflow = self.backend.run_batch(
+                            g.shape, grp_caps, ranges)
         return results

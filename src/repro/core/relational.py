@@ -23,10 +23,20 @@ Design notes (hardware adaptation, see DESIGN.md §2):
 
 Everything is int32 on the hot path (TPU x64 off); values must be
 ``< SENTINEL``.
+
+Each primitive runs under a ``jax.named_scope``, so the ``op_name`` of
+every device op says which primitive it belongs to: ``sort``
+(``lex_argsort``, ``rel_sort``), ``search`` (``lex_searchsorted``,
+``lex_count_matches`` and the ``searchsorted`` of compaction and
+expansion), ``compact`` (``rel_compact``, ``rel_unique``, ``prefix_sum``)
+and ``expand`` (the gathers of ``expand_ranges`` and
+``expansion_join``).  Scopes only add metadata; the compiled programs are
+the same.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Sequence
 
 import jax
@@ -36,6 +46,20 @@ import numpy as np
 SENTINEL = np.int32(2**31 - 1)
 I32 = jnp.int32
 U32 = jnp.uint32
+
+def _scoped(name: str):
+    """Run the decorated function under ``jax.named_scope(name)``, a new
+    scope object per call: a scope object keeps the name stack it
+    replaced, so one shared object (``@jax.named_scope(...)`` on a
+    function) cannot be re-entered, by recursion or from another
+    thread, without leaking its name onto the ops that follow."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return scoped
+    return wrap
 
 
 class Relation(NamedTuple):
@@ -125,6 +149,7 @@ def batch_to_numpy(rel: Relation, lanes=None) -> list[np.ndarray]:
 # ---------------------------------------------------------------------- #
 
 
+@_scoped("sort")
 def lex_argsort(keys: Sequence[jax.Array]) -> jax.Array:
     """Stable lexicographic argsort of parallel key columns.
 
@@ -147,6 +172,7 @@ def lex_argsort(keys: Sequence[jax.Array]) -> jax.Array:
     return jax.lax.fori_loop(0, len(keys), one_key, perm)
 
 
+@_scoped("sort")
 def rel_sort(rel: Relation, num_keys: int | None = None) -> Relation:
     """Sort rows lexicographically by the first ``num_keys`` columns
     (stable).  SENTINEL padding rows sort to the end (values < SENTINEL
@@ -164,6 +190,7 @@ def rel_sort(rel: Relation, num_keys: int | None = None) -> Relation:
 _SCAN_BLOCK = 1024
 
 
+@_scoped("compact")
 def prefix_sum(x: jax.Array) -> jax.Array:
     """Inclusive int32 prefix sum of a 1-D array — the values of
     ``jnp.cumsum`` (wrapping int32 addition is associative), computed as
@@ -183,6 +210,7 @@ def prefix_sum(x: jax.Array) -> jax.Array:
     return (inner + offsets[:, None]).reshape(-1)[:n]
 
 
+@_scoped("compact")
 def rel_compact(rel: Relation, keep: jax.Array) -> Relation:
     """Stable-move rows with keep=True to the front; drop the rest.
 
@@ -193,13 +221,15 @@ def rel_compact(rel: Relation, keep: jax.Array) -> Relation:
     csum = prefix_sum(keep)
     new_count = csum[-1]
     j = jnp.arange(rel.capacity, dtype=I32)
-    src = jnp.clip(jnp.searchsorted(csum, j, side="right"), 0,
-                   rel.capacity - 1)
+    with jax.named_scope("search"):
+        src = jnp.clip(jnp.searchsorted(csum, j, side="right"), 0,
+                       rel.capacity - 1)
     m = j < new_count
     cols = tuple(jnp.where(m, c[src], SENTINEL) for c in rel.cols)
     return Relation(cols, new_count, rel.overflow)
 
 
+@_scoped("compact")
 def rel_unique(rel: Relation, num_keys: int | None = None) -> Relation:
     """Dedup a *sorted* relation on its first ``num_keys`` columns
     (keeps the first row of each group)."""
@@ -252,6 +282,7 @@ def _lex_le(a, b) -> jax.Array:
     return lt | eq
 
 
+@_scoped("search")
 def lex_searchsorted(
     hay: Sequence[jax.Array], needles: Sequence[jax.Array], side: str = "left"
 ) -> jax.Array:
@@ -281,6 +312,7 @@ def lex_searchsorted(
     return lo
 
 
+@_scoped("search")
 def lex_count_matches(hay, needles, hay_count) -> jax.Array:
     """Number of hay rows equal to each needle row (0 for SENTINEL
     needles / rows beyond hay_count)."""
@@ -367,9 +399,10 @@ def expansion_join(
     bj = jnp.clip(bj, 0, b.capacity - 1)
 
     cols = []
-    for which, ci in out_cols:
-        src = a.cols[ci][ai] if which == "a" else b.cols[ci][bj]
-        cols.append(jnp.where(out_valid, src, SENTINEL))
+    with jax.named_scope("expand"):
+        for which, ci in out_cols:
+            src = a.cols[ci][ai] if which == "a" else b.cols[ci][bj]
+            cols.append(jnp.where(out_valid, src, SENTINEL))
     overflow = (a.overflow | b.overflow
                 | overflow_bit(total > out_capacity, bit))
     return Relation(tuple(cols), jnp.minimum(total, out_capacity).astype(I32), overflow)
@@ -387,9 +420,11 @@ def expand_ranges(lo: jax.Array, cnt: jax.Array, out_capacity: int):
     starts = ends - cnt
     t = jnp.arange(out_capacity, dtype=I32)
     # probe row of output row t: first i with ends[i] > t
-    ai = jnp.clip(jnp.searchsorted(ends, t, side="right").astype(I32), 0,
-                  max(n - 1, 0))
-    return ai, lo[ai] + (t - starts[ai]), t < total, total
+    with jax.named_scope("search"):
+        ai = jnp.clip(jnp.searchsorted(ends, t, side="right").astype(I32), 0,
+                      max(n - 1, 0))
+    with jax.named_scope("expand"):
+        return ai, lo[ai] + (t - starts[ai]), t < total, total
 
 
 # ---------------------------------------------------------------------- #

@@ -141,6 +141,7 @@ from .engine import Engine, QueryCaps
 from .index import CPQxIndex
 from .query import CPQ, plan_shape
 from .rpq import RPQ
+from .tracing import span
 from .workload import DEFAULT_TENANT
 
 
@@ -202,9 +203,6 @@ class ServiceStats:
     plan_hits: int = 0
     updates_applied: int = 0  # individual update ops through apply_updates
     update_batches: int = 0  # coalesced mirror/device maintenance rounds
-    retry_rungs: int = 0  # capacity-ladder rungs climbed by this service's
-    # traffic (delta of Engine.telemetry across flushes) — estimator
-    # health beyond wall-clock
     sequences_observed: int = 0  # candidate seqs harvested into the sketch
     adapt_rounds: int = 0  # AdaptationController.propose invocations
     interests_inserted: int = 0  # mined interest insertions drained
@@ -228,6 +226,7 @@ class _Round:
     queries: list  # distinct CPQ queries (the shaped/union batch)
     plans: list
     rpq_queries: list  # distinct RPQ queries (fixpoint evaluation)
+    rid: int = 0  # the service's drain round count when it was taken
     handle: object = None
 
 
@@ -281,7 +280,6 @@ class QueryService:
         self._next_rid = 0
         self._ckpt_step = 0  # next checkpoint step id (monotone)
         self._planned_since_adapt = 0
-        self._rungs_seen = engine.telemetry.retry_rungs
         self._flushing = False  # reentrancy guard for the pipelined drain
         self._adapting = False  # reentrancy guard for adapt()
         self._queue: list[QueryRequest] = []
@@ -385,9 +383,17 @@ class QueryService:
         Queued updates (``apply_updates`` / adaptation proposals) are
         drained first, so every query in this flush is answered on the
         post-update index.  On an engine failure every not-yet-completed
-        request is requeued — accepted requests are never lost."""
+        request is requeued — accepted requests are never lost.
+
+        The flush and each round's three halves are spans
+        (``core.tracing``) carrying a drain round's id: the flush's first
+        round, and each half's own."""
         if self._flushing:
             return []
+        with span("service.flush", round=self.stats.drain_rounds + 1):
+            return self._flush()
+
+    def _flush(self) -> list[QueryRequest]:
         self._flushing = True
         completed: list[QueryRequest] = []
         inflight: _Round | None = None
@@ -396,14 +402,18 @@ class QueryService:
         try:
             self._drain_updates()
             while True:
-                nxt = self._prepare_round(inflight)
+                with span("service.prepare_round",
+                          round=self.stats.drain_rounds + 1):
+                    nxt = self._prepare_round(inflight)
                 if nxt is None and inflight is None:
                     break
                 took = took or nxt is not None
                 if nxt is not None:
-                    self._dispatch_round(nxt)
+                    with span("service.dispatch_round", round=nxt.rid):
+                        self._dispatch_round(nxt)
                 if inflight is not None:
-                    completed.extend(self._finalize_round(inflight))
+                    with span("service.finalize_round", round=inflight.rid):
+                        completed.extend(self._finalize_round(inflight))
                 inflight, nxt = nxt, None
         except Exception:
             requeue = [r for rnd in (inflight, nxt) if rnd is not None
@@ -513,7 +523,8 @@ class QueryService:
         cpq_queries = [q for q in queries if not isinstance(q, RPQ)]
         rpq_queries = [q for q in queries if isinstance(q, RPQ)]
         plans = [self._plan(q) for q in cpq_queries]
-        return _Round(batch, todo, by_query, cpq_queries, plans, rpq_queries)
+        return _Round(batch, todo, by_query, cpq_queries, plans, rpq_queries,
+                      rid=self.stats.drain_rounds)
 
     def _dispatch_round(self, rnd: _Round) -> None:
         if rnd.queries:
@@ -545,11 +556,6 @@ class QueryService:
                 self._cache_put(q, res)
                 for req in rnd.by_query[q]:
                     req.result, req.done, req.t_done = res, True, now
-            # ladder telemetry: fold the engine's rung delta into the
-            # service view (estimator health is a serving-layer signal)
-            rungs = self.engine.telemetry.retry_rungs
-            self.stats.retry_rungs += rungs - self._rungs_seen
-            self._rungs_seen = rungs
         self.stats.served += len(rnd.reqs)
         for req in rnd.reqs:
             self.stats.tenant(req.tenant).served += 1
@@ -743,7 +749,6 @@ class QueryService:
         self._ckpt_step = max(self._ckpt_step, state.step + 1)
         self._pending_updates = []
         self._planned_since_adapt = 0
-        self._rungs_seen = self.engine.telemetry.retry_rungs
         return state.step
 
     # ------------------------------------------------------------------ #
