@@ -15,8 +15,9 @@ Design notes (hardware adaptation, see DESIGN.md §2):
   key, least significant first (``lex_argsort``), then gathers
 * pointer-walk merge join          -> branch-free *vectorized binary
   search* (fixed trip count = bit-length of capacity) + capacity-padded
-  expansion join (prefix sum + searchsorted row recovery)
-* compaction (filter)              -> prefix sum + searchsorted + gather
+  expansion join (prefix sums + one scatter for row recovery)
+* compaction (filter)              -> prefix sums + the same row recovery
+  + gather
 * hash maps                        -> dense ranks (exact, collision-free)
 * per-pair signature sets          -> order-invariant two-lane uint32
   fingerprints (sum of avalanche-mixed rows after exact dedup)
@@ -27,11 +28,17 @@ Everything is int32 on the hot path (TPU x64 off); values must be
 Each primitive runs under a ``jax.named_scope``, so the ``op_name`` of
 every device op says which primitive it belongs to: ``sort``
 (``lex_argsort``, ``rel_sort``), ``search`` (``lex_searchsorted``,
-``lex_count_matches`` and the ``searchsorted`` of compaction and
-expansion), ``compact`` (``rel_compact``, ``rel_unique``, ``prefix_sum``)
-and ``expand`` (the gathers of ``expand_ranges`` and
-``expansion_join``).  Scopes only add metadata; the compiled programs are
-the same.
+``lex_count_matches``), ``compact`` (``rel_compact``, ``rel_unique``,
+``prefix_sum``, and the row recovery of compaction) and ``expand``
+(``expand_ranges``' row recovery and the gathers of ``expand_ranges``
+and ``expansion_join``).  Scopes only add metadata; the compiled
+programs are the same.
+
+Row recovery (which source row feeds output row ``t``) needs no search:
+the start offsets of the source rows (the exclusive prefix sum of their
+output counts) are sorted, so the source of output row ``t`` is the
+number of rows starting at or before ``t``, less one — a scatter-add of
+ones at the starts and a prefix sum (``_row_owners``).
 """
 
 from __future__ import annotations
@@ -190,13 +197,8 @@ def rel_sort(rel: Relation, num_keys: int | None = None) -> Relation:
 _SCAN_BLOCK = 1024
 
 
-@_scoped("compact")
-def prefix_sum(x: jax.Array) -> jax.Array:
-    """Inclusive int32 prefix sum of a 1-D array — the values of
-    ``jnp.cumsum`` (wrapping int32 addition is associative), computed as
-    a row-wise cumsum over ``(n / 1024, 1024)`` tiles plus the running
-    row offsets.  The TPU compiler builds this in seconds, where one flat
-    ``cumsum`` of a million elements takes it tens of seconds."""
+def _prefix_sum(x: jax.Array) -> jax.Array:
+    """:func:`prefix_sum` in the caller's scope."""
     n = x.shape[0]
     x = x.astype(I32)
     if n <= _SCAN_BLOCK:
@@ -206,25 +208,53 @@ def prefix_sum(x: jax.Array) -> jax.Array:
     tiles = jnp.pad(x, (0, pad)).reshape(rows, _SCAN_BLOCK)
     inner = jnp.cumsum(tiles, axis=1, dtype=I32)
     totals = inner[:, -1]
-    offsets = prefix_sum(totals) - totals
+    offsets = _prefix_sum(totals) - totals
     return (inner + offsets[:, None]).reshape(-1)[:n]
+
+
+@_scoped("compact")
+def prefix_sum(x: jax.Array) -> jax.Array:
+    """Inclusive int32 prefix sum of a 1-D array — the values of
+    ``jnp.cumsum`` (wrapping int32 addition is associative), computed as
+    a row-wise cumsum over ``(n / 1024, 1024)`` tiles plus the running
+    row offsets.  The TPU compiler builds this in seconds, where one flat
+    ``cumsum`` of a million elements takes it tens of seconds."""
+    return _prefix_sum(x)
+
+
+def _row_owners(cnt: jax.Array, out_capacity: int):
+    """Row recovery: source row ``i`` owns ``cnt[i]`` consecutive output
+    rows from ``starts[i]``, the exclusive prefix sum of ``cnt``.
+    Returns ``(ai, starts, total)``: for each output row
+    ``t < out_capacity`` the last row ``i`` with ``starts[i] <= t`` —
+    its owner where ``t < total``, else the last row — and
+    ``total = sum(cnt)``.  These are the values of
+    ``clip(searchsorted(ends, t, side="right"), 0, n - 1)``.
+
+    O(n), no search: ``starts`` is sorted, so the last row starting at or
+    before ``t`` is one less than the number of rows that do — one
+    scatter-add of ones at ``starts`` (sorted indices, so the TPU
+    compiler sorts nothing) and a prefix sum over the output rows.  The
+    second prefix sum runs in the caller's scope."""
+    ends = prefix_sum(cnt)  # inclusive
+    total = ends[-1] if cnt.shape[0] > 0 else jnp.int32(0)
+    starts = ends - cnt
+    hits = jnp.zeros(out_capacity, I32).at[starts].add(
+        1, mode="drop", indices_are_sorted=True)
+    return _prefix_sum(hits) - 1, starts, total
 
 
 @_scoped("compact")
 def rel_compact(rel: Relation, keep: jax.Array) -> Relation:
     """Stable-move rows with keep=True to the front; drop the rest.
 
-    Gathers only, no sort and no scatter: output row ``j`` is the first
-    row whose running keep-count exceeds ``j`` (one binary search over
-    the prefix sum)."""
+    No sort and no search: kept row ``i`` is output row ``csum[i] - 1``
+    (``csum`` the running keep-count); :func:`_row_owners` finds each
+    output row's source with one scatter and prefix sums, then one gather
+    per column."""
     keep = keep & valid_mask(rel)
-    csum = prefix_sum(keep)
-    new_count = csum[-1]
-    j = jnp.arange(rel.capacity, dtype=I32)
-    with jax.named_scope("search"):
-        src = jnp.clip(jnp.searchsorted(csum, j, side="right"), 0,
-                       rel.capacity - 1)
-    m = j < new_count
+    src, _, new_count = _row_owners(keep.astype(I32), rel.capacity)
+    m = jnp.arange(rel.capacity, dtype=I32) < new_count
     cols = tuple(jnp.where(m, c[src], SENTINEL) for c in rel.cols)
     return Relation(cols, new_count, rel.overflow)
 
@@ -387,9 +417,8 @@ def expansion_join(
     More than ``out_capacity`` output rows set ``bit`` in the overflow.
 
     The classic TPU-native expansion join: per-a-row match counts from two
-    binary searches, exclusive cumsum for output offsets, then output-row
-    recovery with one more searchsorted over the cumsum — no dynamic
-    shapes, no scatter."""
+    binary searches, then output-row recovery by :func:`expand_ranges`
+    (prefix sums and one scatter) and gathers — no dynamic shapes."""
     nk = len(a_on)
     a_keys = tuple(a.cols[i] for i in a_on)
     lo = lex_searchsorted(b.cols[:nk], a_keys, "left")
@@ -408,23 +437,21 @@ def expansion_join(
     return Relation(tuple(cols), jnp.minimum(total, out_capacity).astype(I32), overflow)
 
 
+@_scoped("expand")
 def expand_ranges(lo: jax.Array, cnt: jax.Array, out_capacity: int):
     """Output-row recovery of an expansion: probe row ``i`` owns
     ``cnt[i]`` consecutive output rows, reading build rows ``lo[i]``,
     ``lo[i] + 1``, ...  Returns ``(ai, bj, valid, total)``: for each
-    output row ``t < out_capacity`` its probe row (clipped) and build row
-    (unclipped), ``t < total``, and the true output count ``total``."""
-    n = cnt.shape[0]
-    ends = prefix_sum(cnt)  # inclusive
-    total = ends[-1] if n > 0 else jnp.int32(0)
-    starts = ends - cnt
+    output row ``t < out_capacity`` its probe row and build row
+    (unclipped), ``t < total``, and the true output count ``total``.
+    Where ``valid`` is False, ``ai`` is the last probe row and ``bj`` is
+    arbitrary.  Row recovery is :func:`_row_owners`, with no search."""
     t = jnp.arange(out_capacity, dtype=I32)
-    # probe row of output row t: first i with ends[i] > t
-    with jax.named_scope("search"):
-        ai = jnp.clip(jnp.searchsorted(ends, t, side="right").astype(I32), 0,
-                      max(n - 1, 0))
-    with jax.named_scope("expand"):
-        return ai, lo[ai] + (t - starts[ai]), t < total, total
+    if cnt.shape[0] == 0:  # no probe rows: every output row is padding
+        z = jnp.zeros_like(t)
+        return z, z, z > 0, jnp.int32(0)
+    ai, starts, total = _row_owners(cnt, out_capacity)
+    return ai, lo[ai] + (t - starts[ai]), t < total, total
 
 
 # ---------------------------------------------------------------------- #
